@@ -9,7 +9,7 @@ first-class DS-simulator workloads at ``repro.core.sim`` import time:
               whole KV cache streamed per head (read-dominated scan)
   mamba_fwd   chunked selective scan — A parked per channel tile, B/C
               re-streamed for every channel tile, chunk I/O + y writeback
-  bq_quant    per-block absmax int8 quantize — strided f32 tile reads,
+  bq_quant    per-block absmax int8 quantize — whole-row f32 tile reads,
               int8 payload + f32 scale writes (the compressible one)
 
 Registration is import-cheap: geometry shims live in each kernel's
@@ -68,7 +68,7 @@ _catalog("mamba_fwd", _MS,
          "captured mamba_scan forward (A parked, B/C re-streamed per tile)",
          b=1, s=1024, d=512, n=16, variant="fwd")
 _catalog("bq_quant", _BQ,
-         "captured block_quant quantize (strided f32 reads, int8+scale writes)",
+         "captured block_quant quantize (row-tile f32 reads, int8+scale writes)",
          r=512, c=2048, variant="quant")
 
 

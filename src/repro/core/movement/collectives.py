@@ -33,12 +33,6 @@ from repro.kernels.block_quant import ops as bq
 Axis = str
 
 
-def _axis_size(axis_name: Axis) -> jax.Array:
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _flatten_pad(x: jax.Array, block: int) -> Tuple[jax.Array, int]:
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % block
@@ -64,7 +58,7 @@ def compressed_all_gather(
     qg = jax.lax.all_gather(q, axis_name, tiled=True)
     sg = jax.lax.all_gather(s, axis_name, tiled=True)
     full = bq.dequantize(qg, sg, x.dtype).reshape(-1)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if pad:
         per = xf.size  # padded elements per shard
         full = full.reshape(n, per)[:, : x.size].reshape(-1)
@@ -90,7 +84,7 @@ def compressed_grad_sync(
         return gm, jnp.zeros((), g.dtype)
 
     assert compress == "int8", compress
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if residual is not None and residual.ndim == g.ndim:
         g = g + residual.astype(g.dtype)
 
@@ -137,7 +131,7 @@ def chunked_all_gather(
     paper's fixed-rate bandwidth partition expressed as an HLO schedule.
     """
     rows = x.shape[0]
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     critical_rows = min(critical_rows, rows)
     parts = []  # (gathered, part_rows)
     if critical_rows:

@@ -369,7 +369,9 @@ def _run_cells_batch(sweep: Sweep, cells: List[Dict[str, Any]],
     (grouped so cells sharing a trace-shape signature land in the same
     worker's TracePool), uncovered cells fall back to the oracle cell
     runner.  Row order matches ``cells`` and results are bit-identical to
-    the python engine regardless of ``workers``."""
+    the python engine regardless of ``workers``.  With ``workers > 1`` it
+    forks a process pool, which a process that holds a TPU (one that has
+    touched a JAX device) must never do: run such a process serially."""
     covered: List[Tuple[int, Dict[str, Any]]] = []
     fallback: List[Tuple[int, Dict[str, Any]]] = []
     sigs: Dict[int, tuple] = {}
@@ -421,7 +423,12 @@ def run_sweep(sweep: Sweep, workers: Optional[int] = None,
     ``sweep.engine`` ("python" = per-cell oracle, "batch" = lockstep batch
     core with oracle fallback for uncovered cells).  Row order always
     matches ``sweep.cells()`` and per-cell results are independent of both
-    ``workers`` and ``engine``."""
+    ``workers`` and ``engine``.
+
+    ``workers > 1`` forks a process pool.  A process that holds a TPU (one
+    that has touched a JAX device) must never start one — a chip belongs to
+    one process, and forking a process whose TPU runtime is live is unsafe —
+    so such a process runs its cells with ``workers=1``."""
     cells = sweep.cells()
     eng = sweep.engine if engine is None else engine
     if eng not in ENGINES:

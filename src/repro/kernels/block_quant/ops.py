@@ -1,8 +1,11 @@
 """jit'd public wrappers for block quantization.
 
-On TPU the Pallas kernel runs natively; elsewhere (this CPU container, and
-inside the dry-run so cost_analysis stays transparent) the pure-jnp reference
-path is used — numerically identical (tests assert exact equality).
+On TPU the Pallas kernel runs natively, for every shape (the kernel takes any
+(R, C) with C % BLOCK == 0, which is every shape quantization is defined
+for).  Elsewhere — CPU tests, and the dry-run, whose cost_analysis must stay
+transparent — the pure-jnp reference path runs unless the caller asks for
+the kernel in interpret mode (``repro.kernels.dispatch``); the two agree to
+within one int8 code (tests/test_kernels.py).
 
 Also the kernel's trace-capture shim (:func:`trace_geometry`): the grid /
 BlockSpec index-map math of ``quantize_pallas`` mirrored into a jax-free
@@ -20,26 +23,19 @@ from repro.kernels.block_quant import ref
 from repro.kernels.block_quant.block_quant import (
     BLOCK, dequantize_pallas, quantize_pallas,
 )
+from repro.kernels.dispatch import use_pallas
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-@functools.partial(jax.jit, static_argnames=("block", "use_kernel", "interpret"))
-def quantize(x: jax.Array, block: int = BLOCK, *, use_kernel: bool = False,
-             interpret: bool = False):
-    """Flattens to 2-D (rows, C), quantizes per block along the last axis."""
+@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+def quantize(x: jax.Array, *, use_kernel: bool = False, interpret: bool = False):
+    """Flattens to 2-D (rows, C), quantizes per BLOCK along the last axis."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim != 2 else x
-    if (use_kernel or _on_tpu()) and block == BLOCK and x2.shape[-1] % BLOCK == 0:
+    if use_pallas("block_quant", use_kernel=use_kernel, interpret=interpret):
         q, s = quantize_pallas(x2, interpret=interpret)
     else:
-        q, s = ref.quantize_ref(x2, block)
-    return q.reshape(shape), s.reshape(*shape[:-1], shape[-1] // block)
+        q, s = ref.quantize_ref(x2, BLOCK)
+    return q.reshape(shape), s.reshape(*shape[:-1], shape[-1] // BLOCK)
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "use_kernel", "interpret"))
@@ -48,9 +44,7 @@ def dequantize(q: jax.Array, scales: jax.Array, dtype=jnp.float32, *,
     shape = q.shape
     q2 = q.reshape(-1, shape[-1]) if q.ndim != 2 else q
     s2 = scales.reshape(q2.shape[0], -1)
-    if (use_kernel or _on_tpu()) and q2.shape[-1] % BLOCK == 0 and (
-        q2.shape[-1] // s2.shape[-1] == BLOCK
-    ):
+    if use_pallas("block_quant", use_kernel=use_kernel, interpret=interpret):
         x = dequantize_pallas(q2, s2, dtype, interpret=interpret)
     else:
         x = ref.dequantize_ref(q2, s2, dtype)
@@ -61,12 +55,14 @@ def trace_geometry(*, r: int, c: int, variant: str = "quant"):
     """Capture shim: the exact grid + index maps of ``quantize_pallas`` for
     an (R, C) f32 input — grid (R/TR, C/TC) with the column-tile axis
     innermost, reading f32 tiles and writing the int8 payload + one f32
-    absmax scale per quantization block."""
+    absmax scale per quantization block.  Geometries tile exactly, so the
+    shim takes only shapes the kernel's tiles divide."""
     from repro.capture.geometry import KernelGeometry, Operand
     from repro.kernels.block_quant.block_quant import _tiles
 
     assert c % BLOCK == 0, f"C={c} must be a multiple of {BLOCK}"
     tr, tc = _tiles(r, c)
+    assert r % tr == 0 and c % tc == 0, (r, c, tr, tc)
     grid = (r // tr, c // tc)
 
     def tile_map(i, j):

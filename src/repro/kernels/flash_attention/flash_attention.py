@@ -38,12 +38,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)  # (BQ, D)
-    k = k_ref[0].astype(jnp.float32)  # (BK, D)
-    v = v_ref[0].astype(jnp.float32)  # (BK, D)
+    q = q_ref[0]  # (BQ, D)
+    k = k_ref[0]  # (BK, D)
+    v = v_ref[0]  # (BK, D)
+    # the MXU multiplies in bf16: f32 operands need the multi-pass
+    # full-precision product (the TPU default is one bf16 pass), bf16
+    # operands are exact in one pass; both accumulate in f32
+    prec = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=prec,
     ) * scale  # (BQ, BK)
 
     qpos = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -65,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32, precision=prec
     )
     m_scr[...] = m_cur
 

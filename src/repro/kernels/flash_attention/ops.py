@@ -1,5 +1,6 @@
-"""jit'd wrapper for flash attention: Pallas on TPU (or interpret mode for
-validation); the memory-bounded chunked-jnp path otherwise.
+"""jit'd wrapper for flash attention: Pallas on TPU (or in interpret mode
+when the caller asks, for validation); the memory-bounded chunked-jnp path
+otherwise (``repro.kernels.dispatch``).
 
 Also the kernel's trace-capture shim (:func:`trace_geometry`): the grid /
 BlockSpec index-map math of ``flash_attention_pallas`` mirrored into a
@@ -13,6 +14,7 @@ import functools
 
 import jax
 
+from repro.kernels.dispatch import use_pallas
 from repro.kernels.flash_attention.flash_attention import (
     DEFAULT_BK,
     DEFAULT_BQ,
@@ -26,10 +28,10 @@ from repro.kernels.flash_attention.flash_attention import (
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     use_kernel: bool = False, interpret: bool = False,
                     bq: int = 128, bk: int = 128):
-    if use_kernel or jax.default_backend() == "tpu":
+    if use_pallas("flash_attention", use_kernel=use_kernel, interpret=interpret):
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, bq=bq, bk=bk,
-            interpret=interpret or jax.default_backend() != "tpu",
+            interpret=interpret,
         )
     from repro.models import nn
 

@@ -1,11 +1,16 @@
 """Pallas TPU kernel: chunked selective scan (Mamba1-style recurrence).
 
 Grid = (B, D / TD, S / CHUNK) with the sequence-chunk axis innermost and
-sequential: the SSM state h (TD, N) persists in VMEM scratch across chunk
-steps (reset at chunk 0).  Within a chunk the recurrence is unrolled as a
-fori_loop over time steps on VPU-resident (TD, N) tiles — the working set
-(CHUNK x TD inputs + TD x N state) stays in VMEM, which is the kernel-level
-analogue of the chunked lax.scan the XLA path uses (models/mamba.py).
+sequential: the SSM state persists in VMEM scratch across chunk steps
+(reset at chunk 0).  Within a chunk the recurrence runs as a fori_loop over
+time steps; step t reads row t of each input block straight from its VMEM
+ref and writes row t of y straight into the output ref (the TPU lowering
+has no value-level dynamic slicing).  The state is held as (N, TD) — the
+channels on the 128-wide lane axis — so the dt and x rows broadcast over
+the state without a relayout and only the short (1, N) B/C rows turn into
+columns.  The working set (CHUNK x TD inputs + N x TD state) stays in VMEM,
+the kernel-level analogue of the chunked lax.scan the XLA path uses
+(models/mamba.py).
 
 Discretization (da = exp(dt*A), dbx = dt*x*B) happens in-kernel so the big
 (S, D, N) tensors are never materialized in HBM — on TPU this kernel turns
@@ -32,30 +37,23 @@ def _scan_kernel(dt_ref, a_ref, b_ref, c_ref, x_ref, y_ref, hlast_ref, h_scr, *,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    dt = dt_ref[0].astype(jnp.float32)  # (CHUNK, TD)
-    a = a_ref[...].astype(jnp.float32)  # (TD, N)
-    bm = b_ref[0].astype(jnp.float32)  # (CHUNK, N)
-    cm = c_ref[0].astype(jnp.float32)  # (CHUNK, N)
-    x = x_ref[0].astype(jnp.float32)  # (CHUNK, TD)
+    a_t = a_ref[...].astype(jnp.float32).T  # (N, TD)
 
-    def step(t, carry):
-        h, ys = carry
-        da_t = jnp.exp(dt[t][:, None] * a)  # (TD, N)
-        dbx_t = (dt[t] * x[t])[:, None] * bm[t][None, :]  # (TD, N)
-        h = da_t * h + dbx_t
-        y_t = jnp.sum(h * cm[t][None, :], axis=-1)  # (TD,)
-        ys = jax.lax.dynamic_update_slice(ys, y_t[None, :], (t, 0))
-        return h, ys
+    def step(t, h):  # h: (N, TD)
+        dt = dt_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, TD)
+        x = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, TD)
+        b_col = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32).T  # (N, 1)
+        c_col = c_ref[0, pl.ds(t, 1), :].astype(jnp.float32).T  # (N, 1)
+        h = jnp.exp(dt * a_t) * h + b_col * (dt * x)
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(h * c_col, axis=0, keepdims=True)
+        return h
 
-    h0 = h_scr[...]
-    ys0 = jnp.zeros((chunk, dt.shape[1]), jnp.float32)
-    h_out, ys = jax.lax.fori_loop(0, chunk, step, (h0, ys0))
+    h_out = jax.lax.fori_loop(0, chunk, step, h_scr[...])
     h_scr[...] = h_out
-    y_ref[0] = ys
 
     @pl.when(ci == n_chunks - 1)
     def _final():
-        hlast_ref[0] = h_out
+        hlast_ref[0] = h_out.T
 
 
 def selective_scan_pallas(dt, a, bmat, cmat, x, *, chunk: int = CHUNK,
@@ -88,7 +86,7 @@ def selective_scan_pallas(dt, a, bmat, cmat, x, *, chunk: int = CHUNK,
             jax.ShapeDtypeStruct((b, s, d), jnp.float32),
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((tile_d, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, tile_d), jnp.float32)],
         interpret=interpret,
     )(dt, a, bmat, cmat, x)
     return y, h_last

@@ -1,6 +1,7 @@
-"""jit'd wrapper for the selective scan: Pallas kernel on TPU, associative
-chunked-scan jnp path elsewhere (models/mamba.py provides the production XLA
-path; ref.py the sequential oracle).
+"""jit'd wrapper for the selective scan: Pallas kernel on TPU (or in
+interpret mode when the caller asks), the sequential ref.py oracle elsewhere
+(``repro.kernels.dispatch``; models/mamba.py provides the production XLA
+path).
 
 Also the kernel's trace-capture shim (:func:`trace_geometry`): the grid /
 BlockSpec index-map math of ``selective_scan_pallas`` mirrored into a
@@ -12,6 +13,7 @@ import functools
 
 import jax
 
+from repro.kernels.dispatch import use_pallas
 from repro.kernels.mamba_scan import ref
 from repro.kernels.mamba_scan.mamba_scan import CHUNK, TILE_D, selective_scan_pallas
 
@@ -19,11 +21,8 @@ from repro.kernels.mamba_scan.mamba_scan import CHUNK, TILE_D, selective_scan_pa
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def selective_scan(dt, a, bmat, cmat, x, *, use_kernel: bool = False,
                    interpret: bool = False):
-    if use_kernel or jax.default_backend() == "tpu":
-        return selective_scan_pallas(
-            dt, a, bmat, cmat, x,
-            interpret=interpret or jax.default_backend() != "tpu",
-        )
+    if use_pallas("mamba_scan", use_kernel=use_kernel, interpret=interpret):
+        return selective_scan_pallas(dt, a, bmat, cmat, x, interpret=interpret)
     return ref.selective_scan_ref(dt, a, bmat, cmat, x)
 
 

@@ -26,10 +26,7 @@ def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):]
     n = int(np.prod(shape))
-    axis_type = getattr(jax.sharding, "AxisType", None)  # absent before jax 0.5
-    if axis_type is None:
-        return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
-    return jax.make_mesh(shape, axes, (axis_type.Auto,) * len(axes),
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes),
                          devices=jax.devices()[:n])
 
 

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import movement as mv
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import nn
@@ -42,8 +43,10 @@ def serve(
     specs = M.model_specs(cfg)
 
     master = nn.init_params(specs, jax.random.key(seed))
-    mv_cfg = mv.DAEMON_DEFAULT if movement == "daemon" else mv.BASELINE
-    params = mv.working_copy(master, mv_cfg) if movement == "daemon" else master
+    # the f32 master is only the source of the working copy: drop it, so a
+    # full-width model does not hold both for the whole run
+    params = mv.working_copy(master, mv.DAEMON_DEFAULT) if movement == "daemon" else master
+    del master
 
     rng = np.random.default_rng(seed)
     total_len = prompt_len + gen_tokens
@@ -52,31 +55,48 @@ def serve(
         batch_in["patches"] = jnp.zeros((batch, cfg.num_prefix_tokens, cfg.d_model), jnp.bfloat16)
     if cfg.family == "audio":
         batch_in["frames"] = jnp.zeros((batch, prompt_len, cfg.d_model), jnp.bfloat16)
+    prefix = cfg.num_prefix_tokens if cfg.family == "vlm" else 0
+
+    # compile both programs ahead of the timed runs, so neither timing
+    # includes a compile
+    t0 = time.perf_counter()
+    prefill = jax.jit(lambda p, b: M.prefill(cfg, p, b)).lower(params, batch_in).compile()
+    cache_shapes = jax.eval_shape(lambda c: _grow_cache(cfg, c, total_len),
+                                  prefill.out_info[1])
+    tok_shape = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    pos_shape = jax.ShapeDtypeStruct((), jnp.int32)
+    decode = jax.jit(steps_lib.make_decode_step(cfg), donate_argnums=(1,)).lower(
+        params, cache_shapes, tok_shape, pos_shape).compile()
+    t_compile = time.perf_counter() - t0
+
+    jax.block_until_ready(prefill(params, batch_in))  # warm-up
+    t0 = time.perf_counter()
+    logits, cache = prefill(params, batch_in)
+    logits.block_until_ready()
+    t_prefill = time.perf_counter() - t0
 
     # prefill builds a cache sized for the prompt; decode appends in a cache
     # sized total_len: re-home the prefill cache into the bigger buffers
-    t0 = time.time()
-    logits, cache = jax.jit(lambda p, b: M.prefill(cfg, p, b))(params, batch_in)
-    logits.block_until_ready()
-    t_prefill = time.time() - t0
-
     cache = _grow_cache(cfg, cache, total_len)
-    decode = jax.jit(steps_lib.make_decode_step(cfg), donate_argnums=(1,))
-
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    jax.block_until_ready((tok, cache))
     out_tokens = [tok]
-    prefix = cfg.num_prefix_tokens if cfg.family == "vlm" else 0
-    t0 = time.time()
+    finite = jnp.all(jnp.isfinite(logits))
+    t0 = time.perf_counter()
     for i in range(gen_tokens - 1):
         pos = jnp.asarray(prompt_len + prefix + i, jnp.int32)
-        tok, logits, cache = decode(params, cache, tok, pos)
+        tok, step_logits, cache = decode(params, cache, tok, pos)
+        finite &= jnp.all(jnp.isfinite(step_logits))
         out_tokens.append(tok)
     jax.block_until_ready(tok)
-    t_decode = time.time() - t0
+    t_decode = time.perf_counter() - t0
     shd.deactivate()
     toks = np.stack([np.asarray(t) for t in out_tokens], axis=1)
     return {
         "tokens": toks,
+        "first_logits": np.asarray(logits, np.float32),
+        "logits_finite": bool(finite),
+        "compile_s": t_compile,
         "prefill_s": t_prefill,
         "decode_s_per_token": t_decode / max(gen_tokens - 1, 1),
         "tokens_per_s": batch * (gen_tokens - 1) / max(t_decode, 1e-9),
@@ -113,12 +133,13 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--movement", default="daemon", choices=["baseline", "daemon"])
     a = ap.parse_args()
+    enable_compile_cache()
     r = serve(
         a.arch, reduced=a.reduced, batch=a.batch, prompt_len=a.prompt_len,
         gen_tokens=a.gen, movement=a.movement,
     )
     print(
-        f"prefill {r['prefill_s']:.2f}s; decode {r['decode_s_per_token']*1e3:.1f} ms/tok; "
+        f"compile {r['compile_s']:.2f}s; prefill {r['prefill_s']:.3f}s; decode {r['decode_s_per_token']*1e3:.1f} ms/tok; "
         f"{r['tokens_per_s']:.1f} tok/s; generated shape {r['tokens'].shape}"
     )
 

@@ -23,6 +23,7 @@ from repro.configs import get_config
 from repro.core import movement as mv
 from repro.data import DataConfig, TokenPipeline
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import nn
@@ -139,6 +140,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     a = ap.parse_args()
+    enable_compile_cache()
     _, _, losses = train(
         a.arch, reduced=a.reduced, steps=a.steps, global_batch=a.batch,
         seq_len=a.seq, movement=a.movement, peak_lr=a.lr,

@@ -131,9 +131,10 @@ def sinusoidal_pos(seq_len: int, d_model: int, offset: jax.Array | int = 0) -> j
 
 # --------------------------------------------------------------------------
 # attention — memory-bounded chunked softmax attention (the XLA path).
-# The Pallas flash kernel (kernels/flash_attention) is the TPU hot path;
-# this jnp version is numerically equivalent and is what the dry-run lowers
-# (keeps cost_analysis() transparent — see DESIGN.md §3).
+# Every model calls this jnp version, on the TPU too; the Pallas flash
+# kernel (kernels/flash_attention) is numerically equivalent but no model
+# calls it yet.  The dry-run lowers this path (keeps cost_analysis()
+# transparent — see DESIGN.md §3).
 # --------------------------------------------------------------------------
 
 

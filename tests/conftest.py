@@ -13,10 +13,22 @@ The fallback supports exactly the strategy surface the suite uses:
 only keyword-style ``@given(name=strategy, ...)``.  Extend it here when a
 test needs more; never re-inline the shim in a test file.
 """
+import importlib.util
 import os
 import zlib
 
 import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def load_chip_smoke():
+    """Import the repo-root ``chip_smoke.py`` script as a module."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 _FORCE_FALLBACK = bool(os.environ.get("REPRO_FORCE_HYPOTHESIS_FALLBACK"))
 
@@ -83,4 +95,4 @@ except ImportError:  # no pip install available: run the fallback sampler
         return deco
 
 
-__all__ = ["HAVE_HYPOTHESIS", "given", "settings", "st"]
+__all__ = ["HAVE_HYPOTHESIS", "given", "load_chip_smoke", "settings", "st"]

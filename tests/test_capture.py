@@ -55,7 +55,7 @@ def test_captured_workload_valid_in_mixes():
 def test_capture_meta_carries_source_kernel():
     meta = capture_meta("bq_quant")
     assert meta["kernel"] == "block_quant"
-    assert meta["grid"] == (2, 4)
+    assert meta["grid"] == (2, 1)  # (512, 2048): two 256-row whole-row tiles
     assert meta["n_accesses"] > 0
     assert set(meta["operands"]) == {"x", "q", "scales"}
 
@@ -230,6 +230,9 @@ def test_shim_constants_match_kernels():
     sc = next(op for op in bq_geom.operands if op.name == "scales")
     x = next(op for op in bq_geom.operands if op.name == "x")
     assert x.shape[1] // sc.shape[1] == bq.BLOCK
+    tr, tc = bq._tiles(*x.shape)
+    assert x.block == (tr, tc) and sc.block == (tr, tc // bq.BLOCK)
+    assert bq_geom.grid == (x.shape[0] // tr, x.shape[1] // tc)
 
 
 def test_fa_gqa_kv_sharing_matches_kernel_math():

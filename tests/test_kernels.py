@@ -9,9 +9,13 @@ from conftest import given, settings, st  # hypothesis-or-fallback shim
 
 from repro.kernels.block_quant import ops as bq_ops
 from repro.kernels.block_quant import ref as bq_ref
-from repro.kernels.block_quant.block_quant import dequantize_pallas, quantize_pallas
+from repro.kernels.block_quant.block_quant import (
+    BLOCK, ROW_ALIGN, TILE_BYTES, _tiles, dequantize_pallas, quantize_pallas,
+)
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.mamba_scan import ops as ms_ops
 from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas
 from repro.kernels.mamba_scan.ref import selective_scan_ref
 
@@ -23,7 +27,11 @@ jax.config.update("jax_platform_name", "cpu")
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("r,c", [(8, 128), (256, 512), (300, 256), (1, 1024)])
+@pytest.mark.parametrize("r,c", [
+    (8, 128), (256, 512), (300, 256), (1, 1024),
+    (8200, 128),  # row tiles overhang R: the last one is padded
+    (40, 25600),  # wider than one column tile: row and column tiles overhang
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_block_quant_matches_ref(r, c, dtype):
     x = (jax.random.normal(jax.random.key(r * c), (r, c), jnp.float32) * 3).astype(dtype)
@@ -53,6 +61,18 @@ def test_block_quant_roundtrip_error_bounded():
     bound = np.abs(blocks).max(-1) / 254 + 1e-7
     err = np.abs(np.asarray(xr) - np.asarray(x)).reshape(64, 4, 128).max(-1)
     assert (err <= bound * 1.01).all()
+
+
+@pytest.mark.parametrize("r,c", [(512, 2048), (61440, 6912), (5120, 17408), (829440, 128),
+                                 (300, 256), (1, 1024)])
+def test_block_quant_tiles_are_tpu_legal(r, c):
+    """Every tile the TPU lowering sees: rows a multiple of 32 (int8
+    packing) or all of R; the scales tile's last dim all of C/BLOCK or a
+    multiple of 128 lanes; an f32 input tile within TILE_BYTES."""
+    tr, tc = _tiles(r, c)
+    assert tr == r or tr % ROW_ALIGN == 0
+    assert tc == c or (tc // BLOCK) % 128 == 0
+    assert tr * tc * 4 <= TILE_BYTES or tr == r
 
 
 def test_block_quant_zero_block():
@@ -190,3 +210,39 @@ def test_mamba_scan_property_decay_bounds(seed):
     x = jnp.asarray(rng.uniform(-1, 1, (b, s, d)), jnp.float32)
     y, h = selective_scan_ref(dt, a, bm, cm, x)
     assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(h)).all()
+
+
+# --------------------------------------------------------------------------
+# wrapper dispatch (repro.kernels.dispatch): off the TPU the kernel runs
+# only in interpret mode, and only when asked for
+# --------------------------------------------------------------------------
+
+
+def _wrapper_calls():
+    x = jnp.ones((8, 256), jnp.float32)
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    dt = jnp.full((1, 128, 256), 0.1)
+    a = -jnp.ones((256, 4))
+    bm = jnp.ones((1, 128, 4))
+    return {
+        "block_quant": lambda **kw: bq_ops.quantize(x, **kw),
+        "flash_attention": lambda **kw: fa_ops.flash_attention(q, q, q, **kw),
+        "mamba_scan": lambda **kw: ms_ops.selective_scan(dt, a, bm, bm, dt, **kw),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["block_quant", "flash_attention", "mamba_scan"])
+def test_use_kernel_without_tpu_raises(kernel):
+    call = _wrapper_calls()[kernel]
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        call(use_kernel=True)
+
+
+@pytest.mark.parametrize("kernel", ["block_quant", "flash_attention", "mamba_scan"])
+def test_use_kernel_interpret_matches_default_path(kernel):
+    call = _wrapper_calls()[kernel]
+    kern = jax.tree.leaves(call(use_kernel=True, interpret=True))
+    plain = jax.tree.leaves(call())
+    for k, p in zip(kern, plain):
+        np.testing.assert_allclose(np.asarray(k, np.float32), np.asarray(p, np.float32),
+                                   atol=1e-4, rtol=1e-4)

@@ -24,7 +24,7 @@ def run_in_subprocess(body: str) -> dict:
         import numpy as np
         from functools import partial
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import movement as mv
         mesh = jax.make_mesh((8,), ("data",))
         """
