@@ -93,7 +93,6 @@ def main() -> None:
     import argparse
 
     from benchmarks import (
-        bench_kernels,
         engine_bench,
         fig2_schemes,
         fig4_multijob,
@@ -106,7 +105,6 @@ def main() -> None:
         fig10_topology,
         fig11_controllers,
         fig12_memside,
-        roofline,
     )
 
     ap = argparse.ArgumentParser()
@@ -170,8 +168,6 @@ def main() -> None:
         ("fig11", lambda: fig11_controllers.run(n_accesses=n_fig11, workers=w, engine=eng)),
         ("fig12", lambda: fig12_memside.run(n_accesses=n_fig12, workers=w, engine=eng)),
         ("engine_bench", lambda: engine_bench.run(n_accesses=n_fig2)),
-        ("kernels", bench_kernels.run),
-        ("roofline", roofline.run),
     ]
     # opt-in sections: run only when explicitly named in --only (the
     # seed-axis variance grid is ~6x a fig6 run — nightly.yml selects it;

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
+from repro.models.scopes import scope
 from repro.optim import adamw, schedule
 
 
@@ -100,7 +101,8 @@ def make_prefill_step(cfg: ModelConfig) -> Callable:
 def make_decode_step(cfg: ModelConfig) -> Callable:
     def decode_step(params, cache, token, pos):
         logits, cache = M.decode_step(cfg, params, cache, token, pos)
-        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with scope("sample"):
+            next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_token, logits, cache
 
     return decode_step

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.models import encdec, hybrid, mamba, nn, transformer
 from repro.models.nn import ParamSpec, logical_constraint
+from repro.models.scopes import scope
 
 LOSS_CHUNK = 256
 COMPUTE_DTYPE = jnp.bfloat16
@@ -72,8 +73,9 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
 
 
 def _embed(cfg: ModelConfig, params, tokens: jax.Array) -> jax.Array:
-    x = params["embed"].astype(COMPUTE_DTYPE)[tokens]
-    return logical_constraint(x, "act_batch", None, None)
+    with scope("embed"):
+        x = params["embed"].astype(COMPUTE_DTYPE)[tokens]
+        return logical_constraint(x, "act_batch", None, None)
 
 
 def _head_weight(cfg: ModelConfig, params) -> jax.Array:
@@ -84,9 +86,9 @@ def _head_weight(cfg: ModelConfig, params) -> jax.Array:
 
 def logits_at(cfg: ModelConfig, params, hidden: jax.Array) -> jax.Array:
     """hidden: (..., d) -> f32 logits (..., V)."""
-    w = _head_weight(cfg, params).astype(COMPUTE_DTYPE)
-    out = jnp.einsum("...d,dv->...v", hidden, w).astype(jnp.float32)
-    return out
+    with scope("lm_head"):
+        w = _head_weight(cfg, params).astype(COMPUTE_DTYPE)
+        return jnp.einsum("...d,dv->...v", hidden, w).astype(jnp.float32)
 
 
 # --------------------------------------------------------------------------
@@ -110,7 +112,8 @@ def forward_hidden(
         x, cache, aux = transformer.trunk_forward(
             cfg, params, x, positions, training=training, make_cache=make_cache
         )
-        x = nn.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        with scope("norm"):
+            x = nn.rms_norm(x, params["ln_f"], cfg.norm_eps)
         if fam == "vlm":
             x = x[:, batch["patches"].shape[1] :]  # loss over text positions only
         return x, cache, aux
@@ -202,14 +205,16 @@ def loss_fn(
 
 def prefill(cfg: ModelConfig, params, batch: Dict[str, jax.Array]):
     hidden, cache, _ = forward_hidden(cfg, params, batch, training=False, make_cache=True)
-    last = hidden[:, -1, :]
+    with scope("lm_head"):
+        last = hidden[:, -1, :]
     return logits_at(cfg, params, last), cache
 
 
 def decode_step(cfg: ModelConfig, params, cache, token: jax.Array, pos: jax.Array):
     """token: (B,) int32, pos: scalar int32 (write position). -> (logits, cache)."""
     fam = cfg.family
-    x = params["embed"].astype(COMPUTE_DTYPE)[token][:, None, :]
+    with scope("embed"):
+        x = params["embed"].astype(COMPUTE_DTYPE)[token][:, None, :]
     if fam in ("dense", "moe", "vlm"):
         x, cache = transformer.trunk_decode(cfg, params, x, cache, pos)
     elif fam == "ssm":
@@ -228,7 +233,8 @@ def decode_step(cfg: ModelConfig, params, cache, token: jax.Array, pos: jax.Arra
         return logits_at(cfg, params, x[:, 0]), cache
     else:
         raise ValueError(fam)
-    x = nn.rms_norm(x, params["ln_f"], cfg.norm_eps)
+    with scope("norm"):
+        x = nn.rms_norm(x, params["ln_f"], cfg.norm_eps)
     return logits_at(cfg, params, x[:, 0]), cache
 
 

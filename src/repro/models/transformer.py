@@ -19,6 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.models import moe as moe_lib
 from repro.models import nn
 from repro.models.nn import ParamSpec, logical_constraint
+from repro.models.scopes import scope
 
 PyTree = Any
 
@@ -125,15 +126,16 @@ def gqa_qkv(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             *, decode: bool = False):
     b, s, _ = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,dk->bsk", x, p["wq"].astype(x.dtype)).reshape(b, s, h, dh)
-    k = jnp.einsum("bsd,dk->bsk", x, p["wk"].astype(x.dtype)).reshape(b, s, kvh, dh)
-    v = jnp.einsum("bsd,dk->bsk", x, p["wv"].astype(x.dtype)).reshape(b, s, kvh, dh)
-    if cfg.qk_norm:
-        q = nn.rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = nn.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if cfg.pos_embed == "rope":
-        q = nn.apply_rope(q, positions, cfg.rope_theta)
-        k = nn.apply_rope(k, positions, cfg.rope_theta)
+    with scope("attn_proj"):
+        q = jnp.einsum("bsd,dk->bsk", x, p["wq"].astype(x.dtype)).reshape(b, s, h, dh)
+        k = jnp.einsum("bsd,dk->bsk", x, p["wk"].astype(x.dtype)).reshape(b, s, kvh, dh)
+        v = jnp.einsum("bsd,dk->bsk", x, p["wv"].astype(x.dtype)).reshape(b, s, kvh, dh)
+        if cfg.qk_norm:
+            q = nn.rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = nn.rms_norm(k, p["k_norm"], cfg.norm_eps)
+        if cfg.pos_embed == "rope":
+            q = nn.apply_rope(q, positions, cfg.rope_theta)
+            k = nn.apply_rope(k, positions, cfg.rope_theta)
     if decode:
         # align with the cache sharding (kv_seq / kv_dh per the active rules)
         # so the einsums against the resident cache never re-shard it; the
@@ -163,18 +165,21 @@ def gqa_attn_forward(
     """Full-sequence attention (train / prefill)."""
     q, k, v = gqa_qkv(cfg, p, x, positions)
     window = cfg.window if cfg.attn_kind == "swa" else 0
-    o = nn.attention(q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk)
-    out = jnp.einsum(
-        "bsk,kd->bsd", o.reshape(o.shape[0], o.shape[1], -1), p["wo"].astype(x.dtype)
-    )
+    with scope("attn_core"):
+        o = nn.attention(q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk)
+    with scope("attn_proj"):
+        out = jnp.einsum(
+            "bsk,kd->bsd", o.reshape(o.shape[0], o.shape[1], -1), p["wo"].astype(x.dtype)
+        )
     cache = None
     if make_cache:
         w = _cache_window(cfg, k.shape[1])
         s = k.shape[1]
         if w < s:  # ring-buffer extraction: keep last w positions at slot p % w
-            sl = (jnp.arange(w) + (s - w)) % w
-            kc = jnp.zeros((k.shape[0], w, *k.shape[2:]), k.dtype).at[:, sl].set(k[:, s - w :])
-            vc = jnp.zeros((v.shape[0], w, *v.shape[2:]), v.dtype).at[:, sl].set(v[:, s - w :])
+            with scope("attn_core"):
+                sl = (jnp.arange(w) + (s - w)) % w
+                kc = jnp.zeros((k.shape[0], w, *k.shape[2:]), k.dtype).at[:, sl].set(k[:, s - w :])
+                vc = jnp.zeros((v.shape[0], w, *v.shape[2:]), v.dtype).at[:, sl].set(v[:, s - w :])
         else:
             kc, vc = k, v
         cache = {"k": kc, "v": vc}
@@ -188,21 +193,23 @@ def gqa_attn_decode(
     positions = pos[None] if pos.ndim == 0 else pos
     q, k_new, v_new = gqa_qkv(cfg, p, x, positions, decode=True)
     w = cache["k"].shape[1]
-    slot = pos % w
-    k = cache["k"].at[:, slot].set(k_new[:, 0])
-    v = cache["v"].at[:, slot].set(v_new[:, 0])
+    with scope("attn_core"):
+        slot = pos % w
+        k = cache["k"].at[:, slot].set(k_new[:, 0])
+        v = cache["v"].at[:, slot].set(v_new[:, 0])
 
-    if cfg.attn_kind == "swa":
-        # ring buffer: slot i holds absolute position pos - ((pos - i) mod w);
-        # everything resident is inside the window by construction.
-        kv_positions = pos - jnp.mod(pos - jnp.arange(w), w)
-        valid = kv_positions >= 0
-        o = _decode_attn_abs(cfg, q, k, v, kv_positions, valid)
-    else:
-        o = nn.attention(
-            q, k, v, causal=False, window=0, chunk=cfg.attn_chunk, kv_len=pos + 1
-        )
-    out = jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[0], 1, -1), p["wo"].astype(x.dtype))
+        if cfg.attn_kind == "swa":
+            # ring buffer: slot i holds absolute position pos - ((pos - i) mod w);
+            # everything resident is inside the window by construction.
+            kv_positions = pos - jnp.mod(pos - jnp.arange(w), w)
+            valid = kv_positions >= 0
+            o = _decode_attn_abs(cfg, q, k, v, kv_positions, valid)
+        else:
+            o = nn.attention(
+                q, k, v, causal=False, window=0, chunk=cfg.attn_chunk, kv_len=pos + 1
+            )
+    with scope("attn_proj"):
+        out = jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[0], 1, -1), p["wo"].astype(x.dtype))
     return out, {"k": k, "v": v}
 
 
@@ -310,7 +317,8 @@ def apply_block(
     make_cache: bool = False,
     causal: bool = True,
 ):
-    h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
+    with scope("norm"):
+        h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.attn_kind == "mla":
         a, cache = mla_attn_forward(cfg, p["attn"], h, positions, make_cache=make_cache)
     else:
@@ -318,29 +326,34 @@ def apply_block(
             cfg, p["attn"], h, positions, make_cache=make_cache, causal=causal
         )
     x = x + a
-    h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if is_moe:
-        f, aux = moe_lib.apply_moe(p["ffn"], h, cfg)
-    else:
-        f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
-        aux = jnp.zeros((), jnp.float32)
+    with scope("norm"):
+        h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
+    with scope("mlp"):
+        if is_moe:
+            f, aux = moe_lib.apply_moe(p["ffn"], h, cfg)
+        else:
+            f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
+            aux = jnp.zeros((), jnp.float32)
     x = x + f
     x = logical_constraint(x, "act_batch", None, None)
     return x, cache, aux
 
 
 def apply_block_decode(cfg: ModelConfig, p, x, cache, pos, *, is_moe: bool):
-    h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
+    with scope("norm"):
+        h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.attn_kind == "mla":
         a, new_cache = mla_attn_decode(cfg, p["attn"], h, cache, pos)
     else:
         a, new_cache = gqa_attn_decode(cfg, p["attn"], h, cache, pos)
     x = x + a
-    h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if is_moe:
-        f, _ = moe_lib.apply_moe(p["ffn"], h, cfg)
-    else:
-        f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
+    with scope("norm"):
+        h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
+    with scope("mlp"):
+        if is_moe:
+            f, _ = moe_lib.apply_moe(p["ffn"], h, cfg)
+        else:
+            f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
     return x + f, new_cache
 
 
@@ -381,7 +394,8 @@ def trunk_forward(
             return (xx, aux + a), cache
 
         body = _remat(body, cfg, training)
-        (x, aux_total), cache = jax.lax.scan(body, (x, aux_total), params[seg.name])
+        with scope("layers"):
+            (x, aux_total), cache = jax.lax.scan(body, (x, aux_total), params[seg.name])
         if make_cache:
             caches[seg.name] = cache
     return x, caches, aux_total
@@ -395,7 +409,8 @@ def trunk_decode(cfg: ModelConfig, params, x, caches, pos):
             xx, new_cache = apply_block_decode(cfg, p_l, xx, cache_l, pos, is_moe=_seg.is_moe)
             return xx, new_cache
 
-        x, new_cache = jax.lax.scan(body, x, (params[seg.name], caches[seg.name]))
+        with scope("layers"):
+            x, new_cache = jax.lax.scan(body, x, (params[seg.name], caches[seg.name]))
         new_caches[seg.name] = new_cache
     return x, new_caches
 

@@ -12,14 +12,22 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and collection in every
 test worker must see the same tests.
 """
+import contextlib
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from conftest import load_chip_smoke
+from repro.configs import get_config
 from repro.kernels.block_quant.block_quant import BLOCK, dequantize_pallas, quantize_pallas
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas
+from repro.launch import steps
+from repro.models import model as M
+from repro.models import nn, scopes
 
 _SMOKE = load_chip_smoke()
 FLASH_CASES = {label: case for label, *case in _SMOKE.flash_cases()}
@@ -87,3 +95,86 @@ def test_mamba_scan_compiles_for_v5e(one_chip, name):
                         f32(b, s, n), f32(b, s, d))
     assert "tpu_custom_call" in hlo
 
+
+
+# danube at full width and 2 of its 24 layers, served as the chip benchmark's
+# chat-decode cell serves it: batches of 16, a 1024-token prompt grown by 256
+DANUBE_LAYERS, BATCH, PROMPT, GEN = 2, 16, 1024, 256
+
+
+def _danube_programs(one_chip):
+    """Compiled text of danube's prefill and decode step for the described chip."""
+    cfg = dataclasses.replace(get_config("h2o-danube-1.8b"), num_layers=DANUBE_LAYERS)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: sds(s.shape, jnp.bfloat16),
+                          nn.abstract_params(M.model_specs(cfg)))
+    kv = sds((DANUBE_LAYERS, BATCH, PROMPT + GEN, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+    prefill = _compiled_hlo(lambda p, b: M.prefill(cfg, p, b), params,
+                            {"tokens": sds((BATCH, PROMPT), jnp.int32)})
+    decode = jax.jit(steps.make_decode_step(cfg), donate_argnums=(1,)).lower(
+        params, {"seg0": {"k": kv, "v": kv}}, sds((BATCH,), jnp.int32),
+        sds((), jnp.int32)).compile().as_text()
+    return {"prefill": prefill, "decode_step": decode}
+
+
+def _instructions(text, table):
+    """(name, opcode, result type) of each instruction that ``table`` labels."""
+    out = []
+    for line in text.splitlines():
+        m = scopes._INSTRUCTION.match(line)
+        if m and m.group(1) in table:
+            result = line.split("=", 1)[1].split("{", 1)[0].strip()
+            out.append((m.group(1), m.group(2), result))
+    return out
+
+
+@pytest.fixture(scope="module")
+def danube(one_chip):
+    return _danube_programs(one_chip)
+
+
+def test_decode_step_parts_for_v5e(danube):
+    """The ops that dominated the decode step's device time on the chip
+    fall in the parts the model names: the 4x-head f32 copy of K and V
+    (``repeat_kv``) and the multiply-reduce fusions of the attention
+    einsums in ``attn_core``; the scan's re-stacking of the cache in
+    ``layer_loop``."""
+    text = danube["decode_step"]
+    table = scopes.op_scopes(text)
+    ins = _instructions(text, table)
+    kvh, dh = 8, 80
+    heads = f"f32[{BATCH},{PROMPT + GEN},{kvh},4,{dh}]"
+    stacked = f"bf16[{DANUBE_LAYERS},{BATCH},{PROMPT + GEN},{kvh},{dh}]"
+    repeats = [n for n, op, r in ins if op == "broadcast" and r == heads]
+    reduces = [n for n, op, _ in ins if op == "fusion" and n.startswith("multiply_reduce_fusion")]
+    restack = [n for n, op, r in ins if "dynamic-update-slice" in f"{n} {op}" and r == stacked]
+    assert len(repeats) == 2 and len(reduces) >= 2 and len(restack) == 2
+    assert {table[n] for n in repeats + reduces} == {"attn_core"}
+    assert {table[n] for n in restack} == {scopes.LAYER_LOOP}
+
+
+def _without_metadata(text):
+    """The program text with each instruction's metadata and the debug-info
+    tables it points into taken out, and instruction names numbered in
+    order of first use (a scope moves the counters that number them)."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(\d+ .*\n)*",
+                  "\n", text)
+    names = {}
+    return re.sub(r"%([\w.\-]+)",
+                  lambda m: "%" + names.setdefault(m.group(1), f"i{len(names)}"), text)
+
+
+def test_scopes_add_only_metadata_for_v5e(one_chip, danube):
+    """With tracing off the scopes cost nothing: without them the compiled
+    prefill and decode step are the same programs."""
+    scoped = danube
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = _danube_programs(one_chip)
+    for name in scoped:
+        assert "attn_core" in scoped[name] and "attn_core" not in bare[name]
+        assert _without_metadata(scoped[name]) == _without_metadata(bare[name]), name
