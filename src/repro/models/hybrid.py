@@ -103,7 +103,7 @@ def apply_shared_block_decode(cfg: ModelConfig, p, x, emb, inv: int, cache, pos)
     q, k_new, v_new = _shared_qkv(cfg, p, hh, inv, positions)
     k = cache["k"].at[:, pos].set(k_new[:, 0])
     v = cache["v"].at[:, pos].set(v_new[:, 0])
-    o = nn.attention(q, k, v, causal=False, chunk=cfg.attn_chunk, kv_len=pos + 1)
+    o = nn.decode_attention(q, k, v, jnp.arange(k.shape[1]) <= pos)
     x = x + jnp.einsum("bsk,kd->bsd", o.reshape(*o.shape[:2], -1), p["wo"].astype(x.dtype))
     cat2 = jnp.concatenate([x, emb], axis=-1)
     hh = nn.rms_norm(cat2, p["ln2"], cfg.norm_eps)

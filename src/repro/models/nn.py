@@ -147,14 +147,11 @@ def attention(
     window: int = 0,
     chunk: int = 1024,
     q_offset: jax.Array | int = 0,
-    kv_len: Optional[jax.Array] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Chunked attention. Peak memory O(B*H*chunk*Skv) instead of O(B*H*Sq*Skv).
 
     ``q_offset``: absolute position of q[:, 0] (decode: the write position).
-    ``kv_len``: if given, keys at positions >= kv_len are masked (ring buffers
-    / partially-filled caches).
     """
     b, sq, h, dh = q.shape
     skv = k.shape[1]
@@ -164,7 +161,7 @@ def attention(
     if sq <= chunk:
         q_pos = jnp.arange(sq) + q_offset
         return _attn_chunk_masked(
-            q, k, v, q_pos, kv_pos, causal=causal, window=window, scale=scale, kv_len=kv_len
+            q, k, v, q_pos, kv_pos, causal=causal, window=window, scale=scale
         )
 
     n = sq // chunk
@@ -174,7 +171,7 @@ def attention(
     def body(_, i):
         q_pos = i * chunk + jnp.arange(chunk) + q_offset
         o = _attn_chunk_masked(
-            qc[i], k, v, q_pos, kv_pos, causal=causal, window=window, scale=scale, kv_len=kv_len
+            qc[i], k, v, q_pos, kv_pos, causal=causal, window=window, scale=scale
         )
         return None, o
 
@@ -182,17 +179,46 @@ def attention(
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, v.shape[-1])
 
 
+def decode_attention(
+    q: jax.Array,  # (B, 1, H, Dh)
+    k: jax.Array,  # (B, S, KVH, Dh), the cache as stored
+    v: jax.Array,  # (B, S, KVH, Dv)
+    mask: jax.Array,  # (S,) bool: which cache slots the token attends to
+) -> jax.Array:
+    """One token's attention over a KV cache, as a grouped-query einsum.
+
+    q's heads are viewed as (KVH, H // KVH) groups, so each cached K and V
+    element is read once, in its own dtype, and never copied per query head
+    (``G = 1`` is MHA).  Scores, mask and softmax in f32; the weighted sum
+    takes bf16 probabilities with f32 accumulation.
+    """
+    b, _, h, dh = q.shape
+    kvh = k.shape[2]
+    qg = q.reshape(b, kvh, h // kvh, dh)
+    scores = jnp.einsum(
+        "bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32
+    ) / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    scores = jnp.where(mask, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum(
+        "bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
+    )
+    return o.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
+
+
 def repeat_kv(k: jax.Array, h: int) -> jax.Array:
-    """(B, S, KVH, D) -> (B, S, H, D).  Materializing the repeat (instead of a
-    grouped einsum) lets the TP axis shard the full `heads` dim — sharding the
-    raw kv_heads dim (often 8) on a 16-way model axis would pad 2x."""
+    """(B, S, KVH, D) -> (B, S, H, D), for prefill and training only (decode
+    reads the cache as stored, ``decode_attention``).  Materializing the
+    repeat (instead of a grouped einsum) lets the TP axis shard the full
+    `heads` dim — sharding the raw kv_heads dim (often 8) on a 16-way model
+    axis would pad 2x."""
     kvh = k.shape[2]
     if kvh == h:
         return k
     return jnp.repeat(k, h // kvh, axis=2)
 
 
-def _attn_chunk_masked(q, k, v, q_pos, kv_pos, *, causal, window, scale, kv_len):
+def _attn_chunk_masked(q, k, v, q_pos, kv_pos, *, causal, window, scale):
     b, c, h, dh = q.shape
     k = repeat_kv(k, h)
     v = repeat_kv(v, h)
@@ -207,8 +233,6 @@ def _attn_chunk_masked(q, k, v, q_pos, kv_pos, *, causal, window, scale, kv_len)
         mask &= kv_pos[None, :] <= q_pos[:, None]
     if window > 0:
         mask &= kv_pos[None, :] > q_pos[:, None] - window
-    if kv_len is not None:
-        mask &= (kv_pos < kv_len)[None, :]
     scores = jnp.where(mask[None, :, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

@@ -7,7 +7,7 @@ The model wraps each part of its dense-family serving path in
     embed      the token gather (``model._embed``, ``model.decode_step``)
     layers     the layer scan (``transformer.trunk_forward`` / ``trunk_decode``)
     attn_proj  q/k/v projections and rope, and the ``wo`` projection
-    attn_core  KV write, repeat_kv, scores, mask, softmax, weighted sum
+    attn_core  KV write, scores, mask, softmax, weighted sum
     mlp        the feed-forward (``nn.swiglu`` / ``moe.apply_moe``)
     norm       the blocks' RMS norms and ``ln_f``
     lm_head    ``model.logits_at``

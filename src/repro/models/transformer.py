@@ -201,32 +201,13 @@ def gqa_attn_decode(
         if cfg.attn_kind == "swa":
             # ring buffer: slot i holds absolute position pos - ((pos - i) mod w);
             # everything resident is inside the window by construction.
-            kv_positions = pos - jnp.mod(pos - jnp.arange(w), w)
-            valid = kv_positions >= 0
-            o = _decode_attn_abs(cfg, q, k, v, kv_positions, valid)
+            mask = pos - jnp.mod(pos - jnp.arange(w), w) >= 0
         else:
-            o = nn.attention(
-                q, k, v, causal=False, window=0, chunk=cfg.attn_chunk, kv_len=pos + 1
-            )
+            mask = jnp.arange(w) <= pos
+        o = nn.decode_attention(q, k, v, mask)
     with scope("attn_proj"):
         out = jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[0], 1, -1), p["wo"].astype(x.dtype))
     return out, {"k": k, "v": v}
-
-
-def _decode_attn_abs(cfg, q, k, v, kv_positions, valid):
-    """Decode attention with explicit absolute kv positions (ring buffers)."""
-    b, _, h, dh = q.shape
-    k = nn.repeat_kv(k, h)
-    v = nn.repeat_kv(v, h)
-    scores = jnp.einsum(
-        "bqhd,bshd->bhs", q, k, preferred_element_type=jnp.float32
-    ) / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    scores = jnp.where(valid[None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum(
-        "bhs,bshd->bhd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    return o[:, None].astype(q.dtype)
 
 
 # ---------------------------- MLA (deepseek) -------------------------------
