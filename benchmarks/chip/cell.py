@@ -2,15 +2,24 @@
 names each cell's configuration and traffic mix and lists the metrics; the
 parts themselves are files of their own:
 
-- ``configs/<config>.json``  the model's sizes as run, in the source's keys;
+- ``configs/<config>.json``  the model's sizes as run, in the source's keys,
+                             and ``model_files``, the name of its module;
+- ``models/<model_files>.py`` everything the benchmark knows of one weight
+                             layout: seeded weights (``shapes``, ``master``,
+                             ``check_layout``), the plain f32 reference
+                             (``reference``) and counts (``prefill_flops``,
+                             ``decode_flops``, ``decode_bytes``,
+                             ``attn_core_flops``, ``attn_core_bytes``);
 - ``traffic/<traffic>.json`` the traffic mix (lengths, batch, loop);
 - ``metrics/<metric>.py``    a per-layer metric's reader, ``read(rec)``;
 - ``limits/<workload>.json`` the limit of each number compared for ``correct``.
 
-Adding a cell, a mix or a metric adds files and entries; no file here changes.
+Adding a cell, a mix, a metric or a weight layout adds files and entries;
+no file here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import replace
@@ -32,7 +41,18 @@ FIELDS = {
     "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings",
     "sliding_window": "window",
+    "n_routed_experts": "num_experts",
+    "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "moe_d_ff",
+    "n_shared_experts": "num_shared_experts",
+    "first_k_dense_replace": "first_dense_layers",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_dim",
+    "qk_rope_head_dim": "qk_rope_dim",
+    "v_head_dim": "v_head_dim",
 }
+# keys of a configuration file that the harness reads itself
+OWN_KEYS = ("arch", "model_files", "reduced")
 
 
 def load_json(path: Path) -> dict:
@@ -45,25 +65,35 @@ def benchmark(root: Path = ROOT) -> dict:
 
 
 def model_sizes(conf: dict) -> dict:
-    """The numbers of a configuration file that the model is built from."""
-    return {k: conf.get(k) for k in FIELDS}
+    """The configuration as the model is built from it: every key of the
+    file but the harness's own, and every key of ``FIELDS`` (None where the
+    file leaves it out)."""
+    out = dict.fromkeys(FIELDS)
+    out.update((k, v) for k, v in conf.items() if k not in OWN_KEYS)
+    return out
 
 
 def model_config(conf: dict):
     """The program's ModelConfig for a configuration file: the registry's
-    architecture with the file's sizes.  A size that differs from the
-    registry's and is not listed in ``reduced`` is an error."""
+    architecture with the file's sizes.  A key of ``FIELDS`` that the file
+    states has to equal the registry's value or be listed in ``reduced`` (a
+    key stated as null counts as 0); a key that the file leaves out has to
+    be zero or unset in the registry.  Anything else is an error."""
     from repro.configs import get_config
 
     base = get_config(conf["arch"])
     kw = {}
     for key, field in FIELDS.items():
-        value = conf.get(key)
-        if key == "sliding_window":
-            value = value or 0
-        if value != getattr(base, field) and key not in conf["reduced"]:
+        ours = getattr(base, field)
+        if key not in conf:
+            if ours:
+                raise ValueError(f"{conf['arch']}: the file leaves out {key}, and the "
+                                 f"program's {field} is {ours!r}")
+            continue
+        value = 0 if conf[key] is None else conf[key]
+        if value != ours and key not in conf["reduced"]:
             raise ValueError(f"{conf['arch']}: {key}={value!r} differs from the program's "
-                             f"{field}={getattr(base, field)!r} and is not in 'reduced'")
+                             f"{field}={ours!r} and is not in 'reduced'")
         kw[field] = value
     return replace(base, **kw)
 
@@ -89,12 +119,26 @@ def load(workload: str, root: Path = ROOT) -> dict:
         "chips": w["chips"],
         "conf": conf,
         "model": model_sizes(conf),
+        "model_files": model_files(conf["model_files"], here),
         "traffic": traffic,
         "end_to_end": e2e,
         "per_layer": per_layer,
         "limits": load_json(limits_path) if limits_path.exists() else None,
         "dir": here,
     }
+
+
+def model_files(name: str, here: Path = HERE):
+    """The module ``models/<name>.py``, loaded once per process."""
+    return _load_module(here / "models" / f"{name}.py")
+
+
+@functools.cache
+def _load_module(path: Path):
+    spec = importlib.util.spec_from_file_location(f"chip_model_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, here: Path = HERE):

@@ -7,7 +7,8 @@ Set-up (counted in ``setup_s``): weights from the seed on the device, the
 cell's own programs compiled (or loaded from the compile cache kept at
 ``benchmarks/chip/.jax_cache``) and run once.  Then the measured window,
 in which nothing may compile.  With ``--trace 1`` the window runs under the
-profiler and the per-layer metrics are read from the trace; otherwise the
+profiler and the per-layer metrics are read from the trace, with the device
+time of each named part of each program (``scope_split.py``); otherwise the
 end-to-end metrics are printed.  Either way, once the window has closed and
 the program's state is freed, what the window produced is compared with a
 plain f32 reference, and ``correct`` says whether every number compared is
@@ -94,6 +95,20 @@ def compare(readings: dict, limits: dict) -> tuple[bool, dict]:
     return ok and len(checks) > 1, checks
 
 
+def reduce_trace(path: str, span_names, texts, scopes) -> dict:
+    """trace.py's reduction of the profiler trace at ``path``, with the
+    device time of each named part of each program (``scopes``,
+    ``conflict_s``), labelled from the compiled programs' ``texts`` by the
+    scope names ``scopes``."""
+    import scope_split
+    import trace as trace_lib
+
+    planes = trace_lib.load_planes(path, span_names)
+    red = trace_lib.reduce_planes(planes, span_names)
+    red.update(scope_split.reduce_scopes(planes, *scope_split.program_tables(texts, scopes)))
+    return red
+
+
 def run_cell(c: dict, seed: int, seconds: float, trace: bool, devices, peaks_row,
              counter=None, movement=None) -> dict:
     """Set-up, window, per-layer reduction and check of one cell, driven by
@@ -104,7 +119,7 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, devices, peaks_row
     loop = __import__(f"{c['traffic']['kind']}_loop")
     cfg = cell_lib.model_config(c["conf"])
     kw = {} if movement is None else {"movement": movement}
-    system = loop.Cell(cfg, c["model"], c["traffic"], seed, **kw)
+    system = loop.Cell(cfg, c, seed, **kw)
     system.warm_up()
     jax.block_until_ready(system.params)
     setup_s = time.perf_counter() - T_PROCESS
@@ -126,6 +141,7 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, devices, peaks_row
     weight_bytes = system.weight_bytes()
     memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
     chosen = system.check_inputs(win)
+    texts = [p.as_text() for progs in system.programs.values() for p in progs] if trace else []
     system.free()
     del system
     t_check = time.perf_counter()
@@ -142,13 +158,20 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool, devices, peaks_row
               "count": len(devices), "memory_peak_bytes": int(memory_peak)}
     metrics, breakdown = {}, None
     if trace:
+        import scope_split
         import trace as trace_lib
 
-        red = trace_lib.reduce_file(trace_lib.find_xplane(trace_dir), loop.HOST_SPANS)
+        t_trace = time.perf_counter()
+        red = reduce_trace(trace_lib.find_xplane(trace_dir), loop.HOST_SPANS, texts,
+                           c["model_files"].SCOPES)
+        del texts
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(f"trace reduction {time.perf_counter() - t_trace:.1f} s")
+        for line in scope_split.describe(red):
+            log(f"scopes {line}")
         device["busy_s"], device["window_s"] = red["busy_s"], red["window_s"]
-        rec = {"model": c["model"], "traffic": c["traffic"], "peaks": peaks_row,
-               "trace": red, "work": loop.work(win, c["traffic"]["batch"]),
+        rec = {"model": c["model"], "model_files": c["model_files"], "traffic": c["traffic"],
+               "peaks": peaks_row, "trace": red, "work": loop.work(win, c["traffic"]["batch"]),
                "weight_bytes": weight_bytes}
         for m in c["per_layer"]:
             value = cell_lib.metric_reader(m["name"], c.get("dir", HERE))(rec)

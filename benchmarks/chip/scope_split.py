@@ -1,89 +1,148 @@
-#!/usr/bin/env python3
 """Device time of each named part of a serving cell's programs, from a
 profiler trace of one window on the chip.
 
-    python3 benchmarks/chip/scope_split.py --workload <cell> --seed <n> --seconds <s> \
-        [--out <file.json>]
+The program names the parts of its serving step with ``jax.named_scope``
+(embedding, attention projections and core, MLP, norms, LM head, sampling,
+and the layer loop around them); the names are HLO metadata (``op_name``).
+The cell's model-files module lists the names its path uses (``SCOPES``).
+``run.py --trace 1`` keeps each compiled program's text before the
+program's state is freed, and then:
 
-The program names the parts of its serving step (``repro.models.scopes``:
-embedding, attention projections and core, MLP, norms, LM head, sampling,
-and the layer loop around them).  This run makes the cell's set-up and one
-traced window as ``run.py --trace 1`` does, keeps each compiled program's
-text, and then:
+- labels the instructions of each program (``op_scopes``,
+  ``program_tables``): each instruction that can run as an operation of its
+  own on the device takes the innermost name of ``SCOPES`` in its
+  ``op_name``.  Two labels are derived rather than named:
 
-- labels the instructions of each program with ``op_scopes``.  The
-  buckets' programs share one name (``jit_decode_step``); an instruction
-  name that two buckets label differently counts as ``unscoped``, and its
-  time is reported as ``conflict_s``;
+      layer_loop  inside ``layers`` but in no block's scope: the scan's
+                  slicing and re-stacking of weights and cache, the loop
+      unscoped    no op_name, or one with no name of ``SCOPES``: layout
+                  copies the compiler adds, async copy and slice starts
+                  and dones
+
+  The buckets' programs share one name
+  (``jit_decode_step``); an instruction name that two buckets label
+  differently counts as ``unscoped``, and its time is reported as
+  ``conflict_s``;
 - names each device operation inside the window by its program (the
   ``XLA Modules`` event around it) and its instruction, and adds its self
   time (trace.py's: a loop keeps only what its body leaves uncovered) to
-  its label;
-- prints one line per program on stderr, with each label's seconds and
-  share of the program's device time, and last on stdout one JSON object:
-  the split (``scopes``), the decode step's per-step numbers (``METRICS``),
-  trace.py's own reduction, and what the scope reduction cost.
+  its label (``reduce_scopes``), into the record's ``trace`` as ``scopes``
+  and ``conflict_s``.
 
-``run.py`` does not report these numbers: its reduction hands the metric
-readers no per-operation times.
+``METRICS`` reads the decode step's per-step numbers from that record; each
+is the reader of one ``metrics/<name>.py``.
 """
 from __future__ import annotations
 
-import argparse
 import bisect
-import json
 import re
-import shutil
-import sys
-import tempfile
-import time
 from collections import defaultdict
 
-import run  # first: puts the program (src/) on sys.path
-import cell as cell_lib
-import count
 import trace as trace_lib
-from repro.models.scopes import UNSCOPED, op_scopes
 
+LAYERS, LAYER_LOOP, UNSCOPED = "layers", "layer_loop", "unscoped"
 DECODE = "jit_decode_step"
 # the weight matmuls; on a TPU some of the weights' reads fall outside them,
 # in the scan's slices (layer_loop) and async fetches (unscoped)
 MATMUL = ("attn_proj", "mlp", "lm_head")
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _OP = re.compile(r"^%?([\w.\-]+) = ")
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# computations whose instructions run one by one: loop bodies and
+# conditions, conditional branches, and the targets of a ``call``
+_SUBCOMPUTATIONS = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+_CALLS = re.compile(r"\b(?:to_apply|calls)=%?([\w.\-]+)")
 
 
-def program_tables(texts) -> tuple[dict, set]:
-    """{program: {instruction: label}} from compiled programs' texts, and
-    the (program, instruction) pairs that two texts of one program label
-    differently, which are left ``unscoped``."""
+def label(op_name: str | None, scopes) -> str:
+    """The label of an instruction with this ``op_name`` (None: none)."""
+    inner = [p for p in (op_name or "").split("/") if p in scopes]
+    if not inner:
+        return UNSCOPED
+    return LAYER_LOOP if inner[-1] == LAYERS else inner[-1]
+
+
+def _computations(hlo_text: str):
+    """{name: [instruction lines]} and the entry computation's name."""
+    comps, entry, current = {}, None, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m and not line.startswith(("HloModule", " ")):
+                current = m.group(2)
+                comps[current] = []
+                if m.group(1):
+                    entry = current
+        elif line.strip() == "}":
+            current = None
+        else:
+            comps[current].append(line)
+    return comps, entry
+
+
+def _op_name(line: str, opcode: str, comps: dict) -> str | None:
+    """An instruction's op_name; a fusion that has none of its own (the
+    CPU compiler leaves them bare) takes that of the last instruction
+    inside it that has one."""
+    op = _OP_NAME.search(line)
+    if op or opcode != "fusion":
+        return op.group(1) if op else None
+    called = _CALLS.search(line)
+    for inner in reversed(comps.get(called.group(1), []) if called else []):
+        op = _OP_NAME.search(inner)
+        if op:
+            return op.group(1)
+    return None
+
+
+def op_scopes(hlo_text: str, scopes) -> dict:
+    """{instruction name: label} of the instructions that a device trace
+    shows as operations: those of the entry computation and of the
+    computations it runs one instruction at a time (while bodies and
+    conditions, conditional branches, calls), never those inside a fusion
+    or a reduction's combiner."""
+    comps, entry = _computations(hlo_text)
+    if entry is None:
+        raise ValueError("no ENTRY computation in the HLO text")
+    out, todo, seen = {}, [entry], set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            out[m.group(1)] = label(_op_name(line, m.group(2), comps), scopes)
+            for sub in _SUBCOMPUTATIONS.finditer(line):
+                if sub.group(1):
+                    todo.append(sub.group(1))
+                else:
+                    todo += [b.strip().lstrip("%") for b in sub.group(2).split(",")]
+            if m.group(2) == "call":
+                todo += _CALLS.findall(line)
+    return out
+
+
+def program_tables(texts, scopes) -> tuple[dict, set]:
+    """{program: {instruction: label}} from compiled programs' texts, by
+    the names ``scopes``, and the (program, instruction) pairs that two
+    texts of one program label differently, which are left ``unscoped``."""
     tables, conflicts = {}, set()
     for text in texts:
         prog = _MODULE.match(text).group(1)
         table = tables.setdefault(prog, {})
-        for name, lab in op_scopes(text).items():
+        for name, lab in op_scopes(text, scopes).items():
             if table.setdefault(name, lab) != lab:
                 conflicts.add((prog, name))
     for prog, name in conflicts:
         tables[prog][name] = UNSCOPED
     return tables, conflicts
-
-
-def load_planes(path: str, span_names) -> list:
-    """The planes of an ``.xplane.pb`` as ``trace.reduce_planes`` takes them:
-    device ops and modules, and the host spans named in ``span_names``."""
-    from jax.profiler import ProfileData
-
-    keep = set(span_names) | {trace_lib.WINDOW}
-    planes = []
-    for plane in ProfileData.from_file(path).planes:
-        if trace_lib._DEVICE_PLANE.match(plane.name):
-            planes.append((plane.name, {ln.name: trace_lib._events(ln) for ln in plane.lines
-                                        if ln.name in ("XLA Ops", "XLA Modules")}))
-        elif plane.name.startswith("/host:"):
-            planes.append((plane.name, {ln.name: trace_lib._events(ln, keep)
-                                        for ln in plane.lines}))
-    return planes
 
 
 def reduce_scopes(planes, tables: dict, conflicts=frozenset()) -> dict:
@@ -138,24 +197,28 @@ def _decode(rec):
 
 
 def _per_step_ms(*labels):
+    """Device ms a step of the decode program under ``labels``; None where
+    the program has none of them (a path that names no such part)."""
     def read(rec):
         d = _decode(rec)
-        return None if d is None else 1e3 * sum(d[0].get(lab, 0.0) for lab in labels) / d[1]
+        if d is None or not any(lab in d[0] for lab in labels):
+            return None
+        return 1e3 * sum(d[0].get(lab, 0.0) for lab in labels) / d[1]
     return read
 
 
 def attn_roofline(rec):
     """Share of the attention core's device time that the roofline says
-    its steps need: per step, max(score and weighted-sum FLOPs / peak, the
-    bf16 keys and values read and written / HBM bytes/s), from count.py."""
+    its steps need: per step, max(the core's FLOPs / peak, the bytes it
+    reads and writes / HBM bytes/s), by the counts of the cell's
+    model-files module."""
     d = _decode(rec)
     positions = rec["work"]["decode_positions"]
     if d is None or d[1] != len(positions) or not d[0].get("attn_core"):
         return None
-    m, b, pk = rec["model"], rec["work"]["batch"], rec["peaks"]
-    need = sum(max(count.attn_flops_per_pair(m) * b * count.attended(m, pos) / pk["bf16_flops"],
-                   count.kv_bytes_per_token(m) * b * (count.attended(m, pos) + 1)
-                   / pk["hbm_bytes_per_s"])
+    mf, m, b, pk = rec["model_files"], rec["model"], rec["work"]["batch"], rec["peaks"]
+    need = sum(max(mf.attn_core_flops(m, b, pos) / pk["bf16_flops"],
+                   mf.attn_core_bytes(m, b, pos) / pk["hbm_bytes_per_s"])
                for pos in positions)
     return 100.0 * need / d[0]["attn_core"]
 
@@ -164,7 +227,7 @@ METRICS = {
     "decode_attn_core_ms": _per_step_ms("attn_core"),
     "decode_attn_roofline": attn_roofline,
     "decode_matmul_ms": _per_step_ms(*MATMUL),
-    "decode_layer_loop_ms": _per_step_ms("layer_loop"),
+    "decode_layer_loop_ms": _per_step_ms(LAYER_LOOP),
     "decode_unscoped_ms": _per_step_ms(UNSCOPED),
 }
 
@@ -180,58 +243,3 @@ def describe(red: dict) -> list:
         out.append(f"{prog}: {parts}; coverage {100 * sum(split.values()) / dev:.2f}% "
                    f"of {dev:.4f} s")
     return out
-
-
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--out", default="")
-    a = ap.parse_args(argv)
-    c = cell_lib.load(a.workload)
-    jax = run.setup_jax()
-    devices, peaks_row = run.require_chips(jax, c["chips"])
-    loop = __import__(f"{c['traffic']['kind']}_loop")
-    system = loop.Cell(cell_lib.model_config(c["conf"]), c["model"], c["traffic"], a.seed)
-    system.warm_up()
-    t0 = time.perf_counter()
-    tables, conflicts = program_tables(p.as_text() for progs in system.programs.values()
-                                       for p in progs)
-    tables_s = time.perf_counter() - t0
-
-    trace_dir = tempfile.mkdtemp(prefix="chipbench_trace_")
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    win = system.window(a.seed, a.seconds)
-    jax.profiler.stop_trace()
-    system.free()
-
-    t0 = time.perf_counter()
-    planes = load_planes(trace_lib.find_xplane(trace_dir), loop.HOST_SPANS)
-    red = trace_lib.reduce_planes(planes, loop.HOST_SPANS)
-    t1 = time.perf_counter()
-    red.update(reduce_scopes(planes, tables, conflicts))
-    t2 = time.perf_counter()
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    rec = {"model": c["model"], "traffic": c["traffic"], "peaks": peaks_row, "trace": red,
-           "work": loop.work(win, c["traffic"]["batch"])}
-    for line in describe(red):
-        print(f"[scopes] {line}", file=sys.stderr, flush=True)
-    out = {"workload": a.workload, "seed": a.seed,
-           "device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
-                      "count": len(devices)},
-           "metrics": {name: read(rec) for name, read in METRICS.items()},
-           "trace": red,
-           "cost_s": {"tables": tables_s, "load_and_reduce": t1 - t0, "scopes": t2 - t1}}
-    if a.out:
-        with open(a.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps(out), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

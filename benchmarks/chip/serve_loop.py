@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-import reference
 import weights
 from repro.core import movement as mv
 from repro.launch import steps as steps_lib
@@ -42,13 +41,16 @@ def prompts(seed: int, batch_index: int, batch: int, length: int, vocab: int) ->
 
 
 class Cell:
-    """The compiled programs of one serving cell, and the working copy."""
+    """The compiled programs of one serving cell (``c``, as ``cell.load``
+    gives it), and the working copy of the weights that the cell's
+    model-files module makes from the seed."""
 
-    def __init__(self, cfg, model: dict, traffic: dict, seed: int,
+    def __init__(self, cfg, c: dict, seed: int,
                  movement: mv.MovementConfig = mv.DAEMON_DEFAULT):
+        mf, model, traffic = c["model_files"], c["model"], c["traffic"]
         self.cfg, self.model, self.traffic, self.seed = cfg, model, traffic, seed
-        weights.check_layout(model, nn.abstract_params(M.model_specs(cfg)))
-        make = jax.jit(lambda k: mv.working_copy(weights.master(model, k), movement))
+        mf.check_layout(model, nn.abstract_params(M.model_specs(cfg)))
+        make = jax.jit(lambda k: mv.working_copy(mf.master(model, k), movement))
         self.params = make(weights.seed_key(seed))
         self.programs = {}
         batch, gen = traffic["batch"], traffic["gen_tokens"]
@@ -138,7 +140,7 @@ def readings(c: dict, seed: int, chosen: list) -> dict:
     by which a served token's reference logit lies below the reference's
     best, and the share of served tokens that are not the reference's best;
     beside them, the mean gap of each prompt length."""
-    gaps = logit_gaps(c["model"], seed, chosen, c["traffic"]["gen_tokens"])
+    gaps = logit_gaps(c["model_files"], c["model"], seed, chosen, c["traffic"]["gen_tokens"])
     if not gaps:
         return {}
     every = np.concatenate(gaps)
@@ -220,22 +222,23 @@ def sample(reqs: list, n: int, seed: int) -> list:
     return [reqs[i][1:] for i in sorted(picked)]
 
 
-def logit_gaps(model: dict, seed: int, chosen: list, gen: int) -> list:
+def logit_gaps(mf, model: dict, seed: int, chosen: list, gen: int) -> list:
     """Per request of ``chosen``, per served token: how far the reference's
     logit of that token lies below the reference's best, over the prompt
-    with its served tokens.  A request still running is read as far as it
-    was served, at the shapes of a finished one, so that each bucket
-    compiles one reference program."""
-    master = jax.jit(lambda k: weights.master(model, k))(weights.seed_key(seed))
+    with its served tokens, by the reference of the model-files module
+    ``mf``.  A request still running is read as far as it was served, at the
+    shapes of a finished one, so that each bucket compiles one reference
+    program."""
+    logits_at = mf.reference(model, weights.seed_key(seed))
     gaps = []
     for prompt, served in chosen:
         n = len(served)
         seq = np.zeros(len(prompt) + gen - 1, np.int32)
         seq[:len(prompt) + n - 1] = np.concatenate([prompt, served[:-1]])
         read = len(prompt) - 1 + np.minimum(np.arange(gen), n - 1)
-        ref = reference.logits_at(model, master, seq, read)[:n]
+        ref = logits_at(seq, read)[:n]
         best = jnp.max(ref, axis=-1)
         mine = jnp.take_along_axis(ref, jnp.asarray(served, jnp.int32)[:, None], axis=-1)[:, 0]
         gaps.append(np.asarray(best - mine))
-    del master
+    del logits_at
     return gaps

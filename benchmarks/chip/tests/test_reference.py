@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 import cell
-import reference
 import weights
 from repro.core import movement as mv
 from repro.launch import steps as steps_lib
@@ -23,8 +22,8 @@ LOGIT_TOL = 0.08
                          ids=["ring", "inside-window", "tied-full"])
 def test_serving_path_matches_reference(tied, window, prompt):
     conf = tiny_model(window=window, tied=tied, kv=4 if tied else 2)
-    m, cfg = cell.model_sizes(conf), cell.model_config(conf)
-    master = weights.master(m, weights.seed_key(3))
+    m, cfg, mf = cell.model_sizes(conf), cell.model_config(conf), cell.model_files("dense")
+    master = mf.master(m, weights.seed_key(3))
     params = mv.working_copy(master, mv.DAEMON_DEFAULT)
     gen = 12
     rng = np.random.default_rng(0)
@@ -38,6 +37,7 @@ def test_serving_path_matches_reference(tied, window, prompt):
         got.append(lg)
     got = np.stack(got, axis=1)
     read = np.arange(prompt - 1, prompt + gen - 1)
+    logits_at = mf.reference(m, weights.seed_key(3))
     for b in range(2):
-        ref = np.asarray(reference.logits_at(m, master, toks[b, :-1], read))
+        ref = np.asarray(logits_at(toks[b, :-1], read))
         assert np.abs(got[b] - ref).max() < LOGIT_TOL

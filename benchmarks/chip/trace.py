@@ -154,16 +154,22 @@ class _Labeller:
         return best
 
 
-def reduce_file(path: str, span_names=None) -> dict:
+def load_planes(path: str, span_names=None) -> list:
+    """The planes of an ``.xplane.pb`` as ``reduce_planes`` takes them: the
+    devices' ops and modules, and the host's spans (only those named in
+    ``span_names`` and the window, where it is given)."""
     from jax.profiler import ProfileData
 
-    pd = ProfileData.from_file(path)
-    planes = []
     keep = None if span_names is None else set(span_names) | {WINDOW}
-    for plane in pd.planes:
+    planes = []
+    for plane in ProfileData.from_file(path).planes:
         if _DEVICE_PLANE.match(plane.name):
             planes.append((plane.name, {ln.name: _events(ln) for ln in plane.lines
                                         if ln.name in ("XLA Ops", "XLA Modules")}))
         elif plane.name.startswith("/host:"):
             planes.append((plane.name, {ln.name: _events(ln, keep) for ln in plane.lines}))
-    return reduce_planes(planes, span_names)
+    return planes
+
+
+def reduce_file(path: str, span_names=None) -> dict:
+    return reduce_planes(load_planes(path, span_names), span_names)
