@@ -184,6 +184,8 @@ def decode_attention(
     k: jax.Array,  # (B, S, KVH, Dh), the cache as stored
     v: jax.Array,  # (B, S, KVH, Dv)
     mask: jax.Array,  # (S,) bool: which cache slots the token attends to
+    k_new: Optional[jax.Array] = None,  # (B, 1, KVH, Dh): the token's own key
+    v_new: Optional[jax.Array] = None,  # (B, 1, KVH, Dv)
 ) -> jax.Array:
     """One token's attention over a KV cache, as a grouped-query einsum.
 
@@ -191,18 +193,35 @@ def decode_attention(
     element is read once, in its own dtype, and never copied per query head
     (``G = 1`` is MHA).  Scores, mask and softmax in f32; the weighted sum
     takes bf16 probabilities with f32 accumulation.
+
+    With ``k_new`` / ``v_new`` the token also attends to its own K and V as
+    one extra key, in the same softmax, so that the cache is read where it
+    lies and written by the caller afterwards (``mask`` then leaves out the
+    slot the token will take).
     """
     b, _, h, dh = q.shape
     kvh = k.shape[2]
     qg = q.reshape(b, kvh, h // kvh, dh)
-    scores = jnp.einsum(
-        "bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32
-    ) / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    scale = jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32) / scale
     scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum(
-        "bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
+    if k_new is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum(
+            "bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        return o.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
+    s_new = jnp.einsum(
+        "bkgd,bkd->bkg", qg, k_new[:, 0], preferred_element_type=jnp.float32
+    ) / scale
+    m = jnp.maximum(scores.max(axis=-1), s_new)
+    e = jnp.exp(scores - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    denom = e.sum(axis=-1) + e_new
+    probs = (e / denom[..., None]).astype(v.dtype)
+    p_new = (e_new / denom).astype(v.dtype)
+    o = jnp.einsum("bkgs,bskd->bkgd", probs, v, preferred_element_type=jnp.float32)
+    o += jnp.einsum("bkg,bkd->bkgd", p_new, v_new[:, 0], preferred_element_type=jnp.float32)
     return o.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
 
 

@@ -19,7 +19,8 @@ that can run as an operation of its own on the device with the innermost
 scope in its ``op_name``.  Two labels are derived rather than scoped:
 
     layer_loop  inside ``layers`` but in no block scope: the scan's slicing
-                and re-stacking of weights and cache, and the loop itself
+                of weights and caches, the stacking of its outputs, and the
+                loop itself
     unscoped    no op_name, or one without any scope: layout copies the
                 compiler adds, async copy and slice starts and dones
 """

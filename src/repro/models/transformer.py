@@ -5,7 +5,8 @@ archs (deepseek-v2-lite with MLA, dbrx) via segment composition.
 Layers are grouped into *segments* of uniform structure; each segment's
 parameters are stacked on a leading ``layers`` axis and executed with
 ``jax.lax.scan`` (keeps HLO size O(1) in depth — an 80L x d8192 model lowers
-in seconds).  Caches are stacked the same way and co-scanned at decode.
+in seconds).  Caches are stacked the same way and co-scanned at decode; a GQA
+cache is only read inside the scan and written once after it (``trunk_decode``).
 """
 from __future__ import annotations
 
@@ -186,28 +187,54 @@ def gqa_attn_forward(
     return out, cache
 
 
-def gqa_attn_decode(
+def gqa_decode_token(
     cfg: ModelConfig, p, x: jax.Array, cache: Dict[str, jax.Array], pos: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode against a (ring) KV cache. x: (B, 1, d), pos scalar."""
+    """One-token decode that reads a layer's (ring) KV cache where it lies.
+    x: (B, 1, d), pos scalar.  The token attends to every resident slot but
+    ``pos % w``, the one it will take, and to its own K and V as one extra
+    key.  Returns the output and the token's K and V, (B, 1, KVH, Dh) each,
+    in the cache's dtype; the caller writes them (``write_token``)."""
     positions = pos[None] if pos.ndim == 0 else pos
     q, k_new, v_new = gqa_qkv(cfg, p, x, positions, decode=True)
     w = cache["k"].shape[1]
     with scope("attn_core"):
-        slot = pos % w
-        k = cache["k"].at[:, slot].set(k_new[:, 0])
-        v = cache["v"].at[:, slot].set(v_new[:, 0])
-
+        k_new = k_new.astype(cache["k"].dtype)
+        v_new = v_new.astype(cache["v"].dtype)
+        slots = jnp.arange(w)
         if cfg.attn_kind == "swa":
             # ring buffer: slot i holds absolute position pos - ((pos - i) mod w);
             # everything resident is inside the window by construction.
-            mask = pos - jnp.mod(pos - jnp.arange(w), w) >= 0
+            mask = pos - jnp.mod(pos - slots, w) >= 0
         else:
-            mask = jnp.arange(w) <= pos
-        o = nn.decode_attention(q, k, v, mask)
+            mask = slots <= pos
+        mask &= slots != pos % w
+        o = nn.decode_attention(q, cache["k"], cache["v"], mask, k_new, v_new)
     with scope("attn_proj"):
         out = jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[0], 1, -1), p["wo"].astype(x.dtype))
-    return out, {"k": k, "v": v}
+    return out, {"k": k_new, "v": v_new}
+
+
+def write_token(cache: Dict[str, jax.Array], new: Dict[str, jax.Array], pos: jax.Array):
+    """Each K and V of ``new`` (..., B, 1, KVH, Dh) written at slot ``pos % w``
+    of ``cache`` (..., B, w, KVH, Dh), one update per array: in place where
+    the cache is donated."""
+    def write(c, n):
+        start = [0] * c.ndim
+        start[-3] = pos % c.shape[-3]
+        return jax.lax.dynamic_update_slice(c, n, start)
+
+    with scope("attn_core"):
+        return jax.tree.map(write, cache, new)
+
+
+def gqa_attn_decode(
+    cfg: ModelConfig, p, x: jax.Array, cache: Dict[str, jax.Array], pos: jax.Array
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode against one layer's (ring) KV cache, returned with
+    the token written at slot ``pos % w``."""
+    out, new = gqa_decode_token(cfg, p, x, cache, pos)
+    return out, write_token(cache, new, pos)
 
 
 # ---------------------------- MLA (deepseek) -------------------------------
@@ -321,12 +348,14 @@ def apply_block(
 
 
 def apply_block_decode(cfg: ModelConfig, p, x, cache, pos, *, is_moe: bool):
+    """One layer of one-token decode.  An MLA layer returns its latent cache
+    with the token written; a GQA layer only the token's K and V."""
     with scope("norm"):
         h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.attn_kind == "mla":
         a, new_cache = mla_attn_decode(cfg, p["attn"], h, cache, pos)
     else:
-        a, new_cache = gqa_attn_decode(cfg, p["attn"], h, cache, pos)
+        a, new_cache = gqa_decode_token(cfg, p["attn"], h, cache, pos)
     x = x + a
     with scope("norm"):
         h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -383,16 +412,22 @@ def trunk_forward(
 
 
 def trunk_decode(cfg: ModelConfig, params, x, caches, pos):
+    """One token through every segment.  A GQA cache goes into the layer
+    scan read-only and comes out as each layer's new K and V alone, written
+    into the stacked cache once after the scan: a cache scanned out (or
+    carried) would be sliced, copied and re-stacked every step.  MLA's
+    latent cache is scanned in and out with the token written per layer."""
     new_caches = {}
     for seg in segments(cfg):
         def body(xx, scanned, _seg=seg):
             p_l, cache_l = scanned
-            xx, new_cache = apply_block_decode(cfg, p_l, xx, cache_l, pos, is_moe=_seg.is_moe)
-            return xx, new_cache
+            return apply_block_decode(cfg, p_l, xx, cache_l, pos, is_moe=_seg.is_moe)
 
         with scope("layers"):
-            x, new_cache = jax.lax.scan(body, x, (params[seg.name], caches[seg.name]))
-        new_caches[seg.name] = new_cache
+            x, out = jax.lax.scan(body, x, (params[seg.name], caches[seg.name]))
+        if cfg.attn_kind != "mla":
+            out = write_token(caches[seg.name], out, pos)
+        new_caches[seg.name] = out
     return x, new_caches
 
 

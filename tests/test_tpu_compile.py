@@ -136,13 +136,31 @@ def danube(one_chip):
     return _danube_programs(one_chip)
 
 
+def _producer(lines, name):
+    """The entry instruction that ``name`` stands for, followed through the
+    tuple a ``while`` passes on unchanged: (opcode, line)."""
+    while True:
+        line = lines[name]
+        op = scopes._INSTRUCTION.match(line).group(2)
+        m = re.search(r"get-tuple-element\(%([\w.\-]+)\), index=(\d+)", line)
+        if op != "get-tuple-element" or not m:
+            return op, line
+        loop = re.search(r" while\(%([\w.\-]+)\)", lines[m.group(1)])
+        state = re.search(r" tuple\(([^)]*)\)", lines[loop.group(1)])
+        name = state.group(1).split(", ")[int(m.group(2))].split("%")[-1]
+
+
 def test_decode_step_parts_for_v5e(danube):
-    """Decode attention reads the cache as stored: no instruction of the
+    """Decode attention reads the cache where it lies: no instruction of the
     compiled decode step holds K or V per query head (the 4x-head f32 copy
-    ``repeat_kv`` made, and the multiply-reduces over it, took most of the
-    step on the chip); both attention einsums are convolutions labelled
-    ``attn_core``; the scan's re-stacking of the cache stays in
-    ``layer_loop``."""
+    ``repeat_kv`` made); both attention einsums are convolutions labelled
+    ``attn_core``; no top-level copy or dynamic-slice fusion has the shape of
+    the stacked cache or of one layer's slab (the layer scan once cut every
+    layer's slab out, copied it to the attention's layout and re-stacked it,
+    each step); the token is written by exactly two whole-cache
+    dynamic-update-slices in the entry, labelled ``attn_core``, on the
+    donated cache parameters, which the module aliases to its cache
+    outputs, so the write is in place."""
     text = danube["decode_step"]
     table = scopes.op_scopes(text)
     ins = _instructions(text, table)
@@ -156,9 +174,29 @@ def test_decode_step_parts_for_v5e(danube):
         holders = [n for n, op, _ in ins if op == "fusion" and re.search(
             rf"%{re.escape(n)} = .*op_name=\"[^\"]*/{spec}/", text)]
         assert holders and {table[n] for n in holders} == {"attn_core"}, spec
+
     stacked = f"bf16[{DANUBE_LAYERS},{BATCH},{slots},{kvh},{dh}]"
-    restack = [n for n, op, r in ins if "dynamic-update-slice" in f"{n} {op}" and r == stacked]
-    assert len(restack) == 2 and {table[n] for n in restack} == {scopes.LAYER_LOOP}
+    slab = re.compile(rf"\w+\[(1,)?{BATCH},{slots},{kvh},{dh}\]$")
+    moved = [n for n, op, r in ins if (r == stacked or slab.match(r)) and (
+        op in ("copy", "dynamic-slice") or op == "fusion" and "dynamic-slice" in n)]
+    assert moved == []
+
+    comps, entry = scopes._computations(text)
+    lines = {scopes._INSTRUCTION.match(line).group(1): line for line in comps[entry]
+             if scopes._INSTRUCTION.match(line)}
+    writes = [n for n, op, r in ins if "dynamic-update-slice" in f"{n} {op}" and r == stacked]
+    assert len(writes) == 2 and set(writes) <= set(lines)
+    assert {table[n] for n in writes} == {"attn_core"}
+    params = {}
+    for n in writes:
+        operand = re.search(r"dynamic-update-slice\(%([\w.\-]+)", lines[n]).group(1)
+        op, line = _producer(lines, operand)
+        assert op == "parameter" and "op_name=\"cache[" in line, n
+        params[n] = int(re.search(r"parameter\((\d+)\)", line).group(1))
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", text.split("\n", 1)[0]))
+    root = next(line for line in comps[entry] if line.lstrip().startswith("ROOT"))
+    outputs = [o.split("%")[-1] for o in re.search(r" tuple\(([^)]*)\)", root).group(1).split(", ")]
+    assert {n: int(aliases[str(outputs.index(n))]) for n in writes} == params
 
 
 def _without_metadata(text):
