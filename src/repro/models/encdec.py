@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import nn, transformer
+from repro.models import kv_cache, nn, transformer
 from repro.models.nn import ParamSpec
 
 
@@ -87,16 +87,18 @@ def _dec_block(cfg, p_l, x, enc_out, positions, *, make_cache):
     a, self_cache = transformer.gqa_attn_forward(
         cfg, p_l["attn"], h, positions, make_cache=make_cache, causal=True
     )
-    x = x + a
-    h = nn.rms_norm(x, p_l["lnx"], cfg.norm_eps)
     ck, cv = _cross_kv(cfg, p_l["cross"], enc_out)
+    x = _cross_and_ffn(cfg, p_l, x + a, ck, cv)
+    return x, {**self_cache, "ck": ck, "cv": cv} if make_cache else None
+
+
+def _cross_and_ffn(cfg: ModelConfig, p_l, x, ck, cv):
+    """A decoder block after its self-attention: cross-attention over the
+    encoder's K/V, then the feed-forward."""
+    h = nn.rms_norm(x, p_l["lnx"], cfg.norm_eps)
     x = x + _cross_attn(cfg, p_l["cross"], h, ck, cv)
     h = nn.rms_norm(x, p_l["ln2"], cfg.norm_eps)
-    x = x + nn.swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"], p_l["ffn"]["w_down"])
-    cache = None
-    if make_cache:
-        cache = {"k": self_cache["k"], "v": self_cache["v"], "ck": ck, "cv": cv}
-    return x, cache
+    return x + nn.swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"], p_l["ffn"]["w_down"])
 
 
 def decode_train(cfg: ModelConfig, params, tokens: jax.Array, enc_out: jax.Array,
@@ -116,30 +118,27 @@ def decode_train(cfg: ModelConfig, params, tokens: jax.Array, enc_out: jax.Array
 
 
 def decode_step(cfg: ModelConfig, params, caches, token: jax.Array, pos: jax.Array):
-    """token: (B,) int32; caches from prefill (self K/V ring + cross K/V)."""
+    """token: (B,) int32; caches from prefill (self K/V ring + cross K/V).
+    The layer scan reads both where they lie and outputs each layer's new
+    self K and V alone, written once after it; the cross K/V stay as they
+    are."""
     x = params["embed"][token][:, None, :].astype(jnp.bfloat16)
     x = x + nn.sinusoidal_pos(1, cfg.d_model, offset=pos).astype(x.dtype)
 
     def body(xx, scanned):
         p_l, c_l = scanned
         h = nn.rms_norm(xx, p_l["ln1"], cfg.norm_eps)
-        a, kv = transformer.gqa_attn_decode(
-            cfg, p_l["attn"], h, {"k": c_l["k"], "v": c_l["v"]}, pos
-        )
-        xx = xx + a
-        h = nn.rms_norm(xx, p_l["lnx"], cfg.norm_eps)
-        xx = xx + _cross_attn(cfg, p_l["cross"], h, c_l["ck"], c_l["cv"])
-        h = nn.rms_norm(xx, p_l["ln2"], cfg.norm_eps)
-        xx = xx + nn.swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"], p_l["ffn"]["w_down"])
-        return xx, {"k": kv["k"], "v": kv["v"], "ck": c_l["ck"], "cv": c_l["cv"]}
+        a, kv = transformer.gqa_decode_token(cfg, p_l["attn"], h, c_l, pos)
+        return _cross_and_ffn(cfg, p_l, xx + a, c_l["ck"], c_l["cv"]), kv
 
-    x, new_caches = jax.lax.scan(body, x, (params["dec"], caches))
-    return nn.rms_norm(x, params["ln_f"], cfg.norm_eps), new_caches
+    x, new = jax.lax.scan(body, x, (params["dec"], caches))
+    written = kv_cache.write({"k": caches["k"], "v": caches["v"]}, new, pos)
+    return nn.rms_norm(x, params["ln_f"], cfg.norm_eps), {**caches, **written}
 
 
 def cache_specs(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Any]:
     l, h, dh = cfg.dec_layers, cfg.num_heads, cfg.head_dim
-    self_shape = (l, batch, seq_len, cfg.num_kv_heads, dh)  # self-attn stores kv heads
+    self_shape = (l, batch, kv_cache.window(cfg, seq_len), cfg.num_kv_heads, dh)
     cross_shape = (l, batch, seq_len, h, dh)  # cross K/V use full heads (MHA proj)
     axes = ("layers", "act_batch", "kv_seq", None, "kv_dh")
     return {

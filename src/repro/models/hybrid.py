@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import mamba, nn
+from repro.models import kv_cache, mamba, nn
 from repro.models.nn import ParamSpec
 
 
@@ -77,40 +77,32 @@ def _shared_qkv(cfg: ModelConfig, p, cat: jax.Array, inv: int, positions: jax.Ar
     return q, k, v
 
 
+def _shared_block(cfg: ModelConfig, p, x, emb, inv: int, positions, attend):
+    """The shared block around ``attend(q, k, v) -> (o, cache)``."""
+    hh = nn.rms_norm(jnp.concatenate([x, emb], axis=-1), p["ln1"], cfg.norm_eps)
+    o, cache = attend(*_shared_qkv(cfg, p, hh, inv, positions))
+    x = x + jnp.einsum("bsk,kd->bsd", o.reshape(*o.shape[:2], -1), p["wo"].astype(x.dtype))
+    hh = nn.rms_norm(jnp.concatenate([x, emb], axis=-1), p["ln2"], cfg.norm_eps)
+    return x + nn.swiglu(hh, p["w_gate"], p["w_up"], p["w_down"]), cache
+
+
 def apply_shared_block(
     cfg: ModelConfig, p, x: jax.Array, emb: jax.Array, inv: int, positions: jax.Array,
     *, make_cache: bool = False,
 ):
-    cat = jnp.concatenate([x, emb], axis=-1)
-    hh = nn.rms_norm(cat, p["ln1"], cfg.norm_eps)
-    q, k, v = _shared_qkv(cfg, p, hh, inv, positions)
-    o = nn.attention(q, k, v, causal=True, chunk=cfg.attn_chunk)
-    x = x + jnp.einsum("bsk,kd->bsd", o.reshape(*o.shape[:2], -1), p["wo"].astype(x.dtype))
-    cat2 = jnp.concatenate([x, emb], axis=-1)
-    hh = nn.rms_norm(cat2, p["ln2"], cfg.norm_eps)
-    g = jnp.einsum("bsd,df->bsf", hh, p["w_gate"].astype(x.dtype))
-    u = jnp.einsum("bsd,df->bsf", hh, p["w_up"].astype(x.dtype))
-    x = x + jnp.einsum("bsf,fd->bsd", nn.silu(g) * u, p["w_down"].astype(x.dtype))
-    cache = {"k": k, "v": v} if make_cache else None
-    return x, cache
+    def attend(q, k, v):
+        o = nn.attention(q, k, v, causal=True, chunk=cfg.attn_chunk)
+        return o, kv_cache.from_prefill(cfg, {"k": k, "v": v}) if make_cache else None
+
+    return _shared_block(cfg, p, x, emb, inv, positions, attend)
 
 
 def apply_shared_block_decode(cfg: ModelConfig, p, x, emb, inv: int, cache, pos):
-    """One token. cache: {k, v: (B, S, KVH, dh)} for this invocation."""
-    positions = pos[None]
-    cat = jnp.concatenate([x, emb], axis=-1)
-    hh = nn.rms_norm(cat, p["ln1"], cfg.norm_eps)
-    q, k_new, v_new = _shared_qkv(cfg, p, hh, inv, positions)
-    k = cache["k"].at[:, pos].set(k_new[:, 0])
-    v = cache["v"].at[:, pos].set(v_new[:, 0])
-    o = nn.decode_attention(q, k, v, jnp.arange(k.shape[1]) <= pos)
-    x = x + jnp.einsum("bsk,kd->bsd", o.reshape(*o.shape[:2], -1), p["wo"].astype(x.dtype))
-    cat2 = jnp.concatenate([x, emb], axis=-1)
-    hh = nn.rms_norm(cat2, p["ln2"], cfg.norm_eps)
-    g = jnp.einsum("bsd,df->bsf", hh, p["w_gate"].astype(x.dtype))
-    u = jnp.einsum("bsd,df->bsf", hh, p["w_up"].astype(x.dtype))
-    x = x + jnp.einsum("bsf,fd->bsd", nn.silu(g) * u, p["w_down"].astype(x.dtype))
-    return x, {"k": k, "v": v}
+    """One token against this invocation's cache {k, v: (B, w, KVH, dh)},
+    read where it lies.  Returns the output and the token's K and V; the
+    trunk writes them (``kv_cache.write``)."""
+    return _shared_block(cfg, p, x, emb, inv, pos[None],
+                         lambda q, k, v: kv_cache.attend(cfg, q, cache, k, v, pos))
 
 
 # --------------------------------------------------------------------------
@@ -163,15 +155,16 @@ def trunk_forward(cfg: ModelConfig, params, x, emb, positions, *, training: bool
 
 
 def trunk_decode(cfg: ModelConfig, params, x, emb, caches, pos):
+    """One token.  Each invocation reads its slab of the stacked attention
+    cache where it lies; their new K and V are written once, at the end."""
     new = dict(caches)
-    ak = caches["attn"]["k"]
-    av = caches["attn"]["v"]
+    attn = caches["attn"]
+    tokens = []
     for inv, (lo, n) in enumerate(_groups(cfg)):
-        x, ac = apply_shared_block_decode(
-            cfg, params["shared"], x, emb, inv, {"k": ak[inv], "v": av[inv]}, pos
+        x, kv = apply_shared_block_decode(
+            cfg, params["shared"], x, emb, inv, {"k": attn["k"][inv], "v": attn["v"][inv]}, pos
         )
-        ak = ak.at[inv].set(ac["k"])
-        av = av.at[inv].set(ac["v"])
+        tokens.append(kv)
 
         def body(xx, scanned):
             p_l, c_l = scanned
@@ -180,13 +173,13 @@ def trunk_decode(cfg: ModelConfig, params, x, emb, caches, pos):
 
         x, sc = jax.lax.scan(body, x, (_mamba_slice(params["mamba"], lo, n), caches[f"ssm{inv}"]))
         new[f"ssm{inv}"] = sc
-    new["attn"] = {"k": ak, "v": av}
+    new["attn"] = kv_cache.write(attn, jax.tree.map(lambda *t: jnp.stack(t), *tokens), pos)
     return x, new
 
 
 def cache_specs(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Any]:
     ninv = n_invocations(cfg)
-    kvshape = (ninv, batch, seq_len, cfg.num_kv_heads, cfg.head_dim)
+    kvshape = (ninv, batch, kv_cache.window(cfg, seq_len), cfg.num_kv_heads, cfg.head_dim)
     axes = (None, "act_batch", "kv_seq", None, "kv_dh")
     out: Dict[str, Any] = {
         "attn": {"k": ParamSpec(kvshape, axes), "v": ParamSpec(kvshape, axes)}

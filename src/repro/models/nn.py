@@ -184,8 +184,8 @@ def decode_attention(
     k: jax.Array,  # (B, S, KVH, Dh), the cache as stored
     v: jax.Array,  # (B, S, KVH, Dv)
     mask: jax.Array,  # (S,) bool: which cache slots the token attends to
-    k_new: Optional[jax.Array] = None,  # (B, 1, KVH, Dh): the token's own key
-    v_new: Optional[jax.Array] = None,  # (B, 1, KVH, Dv)
+    k_new: jax.Array,  # (B, 1, KVH, Dh): the token's own key
+    v_new: jax.Array,  # (B, 1, KVH, Dv)
 ) -> jax.Array:
     """One token's attention over a KV cache, as a grouped-query einsum.
 
@@ -194,10 +194,9 @@ def decode_attention(
     (``G = 1`` is MHA).  Scores, mask and softmax in f32; the weighted sum
     takes bf16 probabilities with f32 accumulation.
 
-    With ``k_new`` / ``v_new`` the token also attends to its own K and V as
-    one extra key, in the same softmax, so that the cache is read where it
-    lies and written by the caller afterwards (``mask`` then leaves out the
-    slot the token will take).
+    The token also attends to its own K and V as one extra key, in the same
+    softmax, so that the cache is read where it lies and written by the
+    caller afterwards (``mask`` leaves out the slot the token will take).
     """
     b, _, h, dh = q.shape
     kvh = k.shape[2]
@@ -205,12 +204,6 @@ def decode_attention(
     scale = jnp.sqrt(jnp.asarray(dh, jnp.float32))
     scores = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32) / scale
     scores = jnp.where(mask, scores, -1e30)
-    if k_new is None:
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum(
-            "bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        return o.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
     s_new = jnp.einsum(
         "bkgd,bkd->bkg", qg, k_new[:, 0], preferred_element_type=jnp.float32
     ) / scale

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import kv_cache
 from repro.models import moe as moe_lib
 from repro.models import nn
 from repro.models.nn import ParamSpec, logical_constraint
@@ -117,12 +118,6 @@ def lm_specs(cfg: ModelConfig) -> Dict[str, Any]:
 # --------------------------------------------------------------------------
 
 
-def _cache_window(cfg: ModelConfig, seq_len: int) -> int:
-    if cfg.attn_kind == "swa":
-        return min(cfg.window, seq_len)
-    return seq_len
-
-
 def gqa_qkv(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             *, decode: bool = False):
     b, s, _ = x.shape
@@ -172,18 +167,7 @@ def gqa_attn_forward(
         out = jnp.einsum(
             "bsk,kd->bsd", o.reshape(o.shape[0], o.shape[1], -1), p["wo"].astype(x.dtype)
         )
-    cache = None
-    if make_cache:
-        w = _cache_window(cfg, k.shape[1])
-        s = k.shape[1]
-        if w < s:  # ring-buffer extraction: keep last w positions at slot p % w
-            with scope("attn_core"):
-                sl = (jnp.arange(w) + (s - w)) % w
-                kc = jnp.zeros((k.shape[0], w, *k.shape[2:]), k.dtype).at[:, sl].set(k[:, s - w :])
-                vc = jnp.zeros((v.shape[0], w, *v.shape[2:]), v.dtype).at[:, sl].set(v[:, s - w :])
-        else:
-            kc, vc = k, v
-        cache = {"k": kc, "v": vc}
+    cache = kv_cache.from_prefill(cfg, {"k": k, "v": v}) if make_cache else None
     return out, cache
 
 
@@ -194,47 +178,14 @@ def gqa_decode_token(
     x: (B, 1, d), pos scalar.  The token attends to every resident slot but
     ``pos % w``, the one it will take, and to its own K and V as one extra
     key.  Returns the output and the token's K and V, (B, 1, KVH, Dh) each,
-    in the cache's dtype; the caller writes them (``write_token``)."""
+    in the cache's dtype; the trunk writes them (``kv_cache.write``)."""
     positions = pos[None] if pos.ndim == 0 else pos
     q, k_new, v_new = gqa_qkv(cfg, p, x, positions, decode=True)
-    w = cache["k"].shape[1]
     with scope("attn_core"):
-        k_new = k_new.astype(cache["k"].dtype)
-        v_new = v_new.astype(cache["v"].dtype)
-        slots = jnp.arange(w)
-        if cfg.attn_kind == "swa":
-            # ring buffer: slot i holds absolute position pos - ((pos - i) mod w);
-            # everything resident is inside the window by construction.
-            mask = pos - jnp.mod(pos - slots, w) >= 0
-        else:
-            mask = slots <= pos
-        mask &= slots != pos % w
-        o = nn.decode_attention(q, cache["k"], cache["v"], mask, k_new, v_new)
+        o, new = kv_cache.attend(cfg, q, cache, k_new, v_new, pos)
     with scope("attn_proj"):
         out = jnp.einsum("bsk,kd->bsd", o.reshape(o.shape[0], 1, -1), p["wo"].astype(x.dtype))
-    return out, {"k": k_new, "v": v_new}
-
-
-def write_token(cache: Dict[str, jax.Array], new: Dict[str, jax.Array], pos: jax.Array):
-    """Each K and V of ``new`` (..., B, 1, KVH, Dh) written at slot ``pos % w``
-    of ``cache`` (..., B, w, KVH, Dh), one update per array: in place where
-    the cache is donated."""
-    def write(c, n):
-        start = [0] * c.ndim
-        start[-3] = pos % c.shape[-3]
-        return jax.lax.dynamic_update_slice(c, n, start)
-
-    with scope("attn_core"):
-        return jax.tree.map(write, cache, new)
-
-
-def gqa_attn_decode(
-    cfg: ModelConfig, p, x: jax.Array, cache: Dict[str, jax.Array], pos: jax.Array
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode against one layer's (ring) KV cache, returned with
-    the token written at slot ``pos % w``."""
-    out, new = gqa_decode_token(cfg, p, x, cache, pos)
-    return out, write_token(cache, new, pos)
+    return out, new
 
 
 # ---------------------------- MLA (deepseek) -------------------------------
@@ -333,7 +284,22 @@ def apply_block(
         a, cache = gqa_attn_forward(
             cfg, p["attn"], h, positions, make_cache=make_cache, causal=causal
         )
-    x = x + a
+    x, aux = _ffn(cfg, p, x + a, is_moe)
+    return logical_constraint(x, "act_batch", None, None), cache, aux
+
+
+def apply_block_decode(cfg: ModelConfig, p, x, cache, pos, *, is_moe: bool):
+    """One layer of one-token decode.  An MLA layer returns its latent cache
+    with the token written; a GQA layer only the token's K and V."""
+    with scope("norm"):
+        h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
+    decode_attn = mla_attn_decode if cfg.attn_kind == "mla" else gqa_decode_token
+    a, new_cache = decode_attn(cfg, p["attn"], h, cache, pos)
+    return _ffn(cfg, p, x + a, is_moe)[0], new_cache
+
+
+def _ffn(cfg: ModelConfig, p, x, is_moe: bool):
+    """A block's second half: x plus its feed-forward, and the MoE aux loss."""
     with scope("norm"):
         h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
     with scope("mlp"):
@@ -342,29 +308,7 @@ def apply_block(
         else:
             f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
             aux = jnp.zeros((), jnp.float32)
-    x = x + f
-    x = logical_constraint(x, "act_batch", None, None)
-    return x, cache, aux
-
-
-def apply_block_decode(cfg: ModelConfig, p, x, cache, pos, *, is_moe: bool):
-    """One layer of one-token decode.  An MLA layer returns its latent cache
-    with the token written; a GQA layer only the token's K and V."""
-    with scope("norm"):
-        h = nn.rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn_kind == "mla":
-        a, new_cache = mla_attn_decode(cfg, p["attn"], h, cache, pos)
-    else:
-        a, new_cache = gqa_decode_token(cfg, p["attn"], h, cache, pos)
-    x = x + a
-    with scope("norm"):
-        h = nn.rms_norm(x, p["ln2"], cfg.norm_eps)
-    with scope("mlp"):
-        if is_moe:
-            f, _ = moe_lib.apply_moe(p["ffn"], h, cfg)
-        else:
-            f = nn.swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
-    return x + f, new_cache
+    return x + f, aux
 
 
 # --------------------------------------------------------------------------
@@ -426,7 +370,7 @@ def trunk_decode(cfg: ModelConfig, params, x, caches, pos):
         with scope("layers"):
             x, out = jax.lax.scan(body, x, (params[seg.name], caches[seg.name]))
         if cfg.attn_kind != "mla":
-            out = write_token(caches[seg.name], out, pos)
+            out = kv_cache.write(caches[seg.name], out, pos)
         new_caches[seg.name] = out
     return x, new_caches
 
@@ -438,7 +382,7 @@ def trunk_decode(cfg: ModelConfig, params, x, caches, pos):
 
 def cache_specs(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Any]:
     out = {}
-    w = _cache_window(cfg, seq_len)
+    w = kv_cache.window(cfg, seq_len)
     for seg in segments(cfg):
         if cfg.attn_kind == "mla":
             out[seg.name] = {

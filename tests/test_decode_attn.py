@@ -58,18 +58,24 @@ MASKS = {"ring_unfilled": ("ring", 9), "ring_wrapped": ("ring", 2 * W + 5),
 @pytest.mark.parametrize("mask_kind", list(MASKS))
 @pytest.mark.parametrize("h,kvh", [(32, 8), (40, 8), (36, 36)])
 def test_grouped_core_matches_repeat_kv(h, kvh, mask_kind):
+    """The token attends to the cache with its own slot masked off and to
+    its own K and V as one extra key; the oracle writes them into that slot
+    first and runs the repeat-kv core over the same mask."""
     kind, pos = MASKS[mask_kind]
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(h * 100 + kvh + pos), 3)
+    kq, kk, kv, kn = jax.random.split(jax.random.PRNGKey(h * 100 + kvh + pos), 4)
     q = jax.random.normal(kq, (B, 1, h, DH), jnp.bfloat16)
     k = jax.random.normal(kk, (B, W, kvh, DH), jnp.bfloat16)
     v = jax.random.normal(kv, (B, W, kvh, DH), jnp.bfloat16)
+    k_new, v_new = jax.random.normal(kn, (2, B, 1, kvh, DH), jnp.bfloat16)
+    slot = pos % W
+    k_in, v_in = k.at[:, slot].set(k_new[:, 0]), v.at[:, slot].set(v_new[:, 0])
     if kind == "ring":
         mask = _ring_mask(jnp.asarray(pos, jnp.int32), W)
-        want = _repeat_kv_core(q, k, v, mask)
+        want = _repeat_kv_core(q, k_in, v_in, mask)
     else:
         mask = jnp.arange(W) <= pos
-        want = _repeat_kv_full(q, k, v, pos)
-    got = nn.decode_attention(q, k, v, mask)
+        want = _repeat_kv_full(q, k_in, v_in, pos)
+    got = nn.decode_attention(q, k, v, mask & (jnp.arange(W) != slot), k_new, v_new)
     assert got.shape == want.shape == (B, 1, h, DH) and got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                rtol=BF16_ULP, atol=BF16_ULP)
